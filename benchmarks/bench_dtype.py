@@ -62,6 +62,7 @@ def _inner(*, res: int, n_local: int, views: int, reps: int):
     from repro.core.tiling import TileGrid, splat_features
     from repro.core.train import GSOptState, GSTrainCfg
     from repro.data.isosurface import point_cloud_for
+    from repro.launch.mesh import make_mesh
     from repro.runtime.checkpoint import CheckpointManager, quantize_cold
 
     K = 16
@@ -76,7 +77,7 @@ def _inner(*, res: int, n_local: int, views: int, reps: int):
                         opacity=0.8)
     g_b = jax.tree.map(lambda x: x[None], g_all)       # (P=1, N, ...)
 
-    mesh = jax.make_mesh((N_DEV,), ("part",))
+    mesh = make_mesh((N_DEV,), ("part",))
     g_sh, opt_sh, b_sh = gs_shardings(mesh, views=views)
     g_dev = jax.device_put(g_b, g_sh)
     cam_dev = jax.device_put(cam_b, b_sh["cam"])

@@ -67,6 +67,7 @@ def _inner(*, res: int, n_total: int, n_dev: int, views: int, reps: int,
     # exists for.  point_cloud_for returns ~n points, so over-request and
     # slice.
     from repro.data.isosurface import point_cloud_for
+    from repro.launch.mesh import make_mesh
     pts, cols = point_cloud_for("kingsnake", int(n_total * 1.5))
     assert pts.shape[0] >= n_total, pts.shape
     pts, cols = pts[:n_total], cols[:n_total]
@@ -84,7 +85,7 @@ def _inner(*, res: int, n_total: int, n_dev: int, views: int, reps: int,
                         opacity=0.8)
     g_b = jax.tree.map(lambda x: x[None], g_all)       # (P=1, N, ...)
 
-    mesh = jax.make_mesh((n_dev,), ("part",))
+    mesh = make_mesh((n_dev,), ("part",))
     g_sh, opt_sh, b_sh = gs_shardings(mesh, views=views)
     g_dev = jax.device_put(g_b, g_sh)
     cam_dev = jax.device_put(cam_b, b_sh["cam"])

@@ -44,6 +44,7 @@ from repro.core.distributed import fit_partitions
 from repro.core.pipeline import build_scene, prepare_timestep
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg
+from repro.launch.mesh import make_mesh
 
 
 def _fit(td, cams, grid, cfg, mesh, *, steps, key, warm=None,
@@ -71,7 +72,7 @@ def run(*, steps: int = 24, res: int = 32, n_views: int = 4,
     grid = TileGrid(res, res, 8, 16)
     cfg = GSTrainCfg(K=16, lambda_dssim=0.0, bg=0.0, view_batch=2,
                      lr_colors=5e-2)
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("part", "view"))
+    mesh = make_mesh((len(jax.devices()), 1), ("part", "view"))
     cap0 = -(-int(ds.n_points * ds.capacity_factor) // len(jax.devices())) \
         * len(jax.devices())
     key = jax.random.PRNGKey(0)

@@ -43,6 +43,7 @@ from repro.core.gaussians import from_points
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, GSOptState
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 
 p, v = %(p)d, %(v)d
 Pn, N, res, V, steps = 1, %(n)d, %(res)d, %(views)d, %(steps)d
@@ -57,7 +58,7 @@ cam_b = select(cams, jnp.arange(V))
 gt = jnp.full((V, Pn * grid.n_tiles, 3, grid.tile_h, grid.tile_w), 0.5)
 mask = jnp.ones((V, Pn * grid.n_tiles, grid.tile_h, grid.tile_w), bool)
 
-mesh = jax.make_mesh((p, v), ("part", "view"))
+mesh = make_mesh((p, v), ("part", "view"))
 cfg = GSTrainCfg(K=32)                      # tiered by default
 g_sh, opt_sh, b_sh = gs_shardings(mesh, views=V)
 # production shape: probe measured tier caps first (the tier_caps=None

@@ -66,8 +66,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.cameras import CAM_VAXES, Camera, select
@@ -89,6 +88,7 @@ from repro.core.train import (GSTrainCfg, GSOptState, _check_resume_policy,
 from repro.optim.compress import compress_grads
 from repro.kernels import rasterize_tiles
 from repro.kernels.ops import rasterize_tiles_tiered
+from repro.launch.mesh import check_auto_mesh
 
 NEG = -1e30
 
@@ -179,6 +179,21 @@ def gs_shardings(mesh, *, views: Optional[int] = None):
                       width=ns(P()), height=ns(P())),
     }
     return g, opt, batch
+
+
+def _all_gather_rows(x, axis_name, nax: int):
+    """Tiled all-gather of splat table ``x`` along its row axis ``nax``.
+
+    The collective runs on a (rows, rest) view — rows leading, every other
+    dim flattened into one lane-dense minor axis — and the result is moved
+    back.  Gathering the (..., N, C) table in place lets the TPU compiler
+    lay it out rows-major with the narrow C axis minor, which pads C (and
+    the columns the table is built from) to 128 lanes: 18 GB of
+    temporaries for a four-chip kingsnake step that needs under 1 GB."""
+    xt = jnp.moveaxis(x, nax, 0)
+    rows = xt.reshape(xt.shape[0], -1)
+    got = lax.all_gather(rows, axis_name, axis=0, tiled=True)
+    return jnp.moveaxis(got.reshape((-1,) + xt.shape[1:]), 0, nax)
 
 
 # ---------------------------------------------------------------------------
@@ -709,8 +724,7 @@ def make_gs_forward(mesh, grid: TileGrid, *, K: int, impl: str = "auto",
             # ---- Grendel handoff: all-gather the SMALL projected table
             # over "part".  bwd(all_gather) = psum_scatter -> grads return
             # sharded.
-            tabs = tuple(lax.all_gather(x, data, axis=nax, tiled=True)
-                         for x in tabs_l)
+            tabs = tuple(_all_gather_rows(x, data, nax) for x in tabs_l)
             if views:
                 # fold the LOCAL view axis into the partition axis:
                 # (Vl, Pl, ...) -> (Vl*Pl, ...) — stage 2 and the kernel
@@ -942,7 +956,7 @@ def make_gs_forward(mesh, grid: TileGrid, *, K: int, impl: str = "auto",
         return loss
 
     return shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+                     out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -1100,7 +1114,7 @@ def make_gs_probe(mesh, grid: TileGrid, *, k_tiers, views: Optional[int] = None,
         return counts, max_occ
 
     return shard_map(shard_fn, mesh=mesh, in_specs=(g_spec, cam_spec),
-                     out_specs=(P(), P()), check_rep=False)
+                     out_specs=(P(), P()), check_vma=False)
 
 
 def folded_tile_count(mesh, grid: TileGrid, n_parts: int,
@@ -1503,7 +1517,7 @@ def make_gs_exchange_probe(mesh, grid: TileGrid, *,
         return lax.pmax(m, reduce_axes) if reduce_axes else m
 
     return shard_map(shard_fn, mesh=mesh, in_specs=(g_spec, cam_spec),
-                     out_specs=P(), check_rep=False)
+                     out_specs=P(), check_vma=False)
 
 
 @functools.lru_cache(maxsize=32)
@@ -1606,6 +1620,7 @@ def make_gs_train_step(mesh, cfg: GSTrainCfg, grid: TileGrid, extent: float,
     the signature, donation pattern and compiled program are exactly the
     pre-knob ones.
     """
+    check_auto_mesh(mesh)
     if k_tiers is _FROM_CFG:
         k_tiers = cfg.resolved_k_tiers()
     if assign_impl is _FROM_CFG:
@@ -1938,6 +1953,7 @@ def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
     checkpoint takes precedence.  ``densify_cap=`` bounds the LIVE splat
     count per partition during densify (see ``GSTrainCfg.densify_cap``).
     """
+    check_auto_mesh(mesh)
     if grid is None:
         grid = TileGrid(cams.width, cams.height, cfg.tile_h, cfg.tile_w)
     if key is None:
@@ -2020,7 +2036,11 @@ def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
                 and (i + 1) % densify_every == 0:
             key = jax.random.split(key, 1 + Pn)[0]
 
-    g_dev = jax.device_put(g, g_sh)
+    # the step donates its state: copy the caller's arrays first (a
+    # device_put onto a device they already live on may alias them —
+    # even with may_alias=False for a replicated sharding — and the first
+    # step would then delete the caller's gaussians)
+    g_dev = jax.device_put(jax.tree.map(jnp.copy, g), g_sh)
     opt_dev = jax.device_put(opt, opt_sh)
     err_dev = jax.device_put(err, err_sh) if compress == "int8" else None
 

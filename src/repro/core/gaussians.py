@@ -105,9 +105,17 @@ def quat_to_rotmat(q):
     )
 
 
+def small_matmul(a, b):
+    """(..., m, k) x (..., k, n) -> (..., m, n) for tiny m, k, n, as an
+    elementwise multiply-and-sum: exact f32 on every backend (a TPU dot
+    defaults to bf16 passes) and free of the dot's (k, n)-minor layout,
+    which pads each tiny matrix of a splat table to a full (8, 128) tile."""
+    return (a[..., :, :, None] * b[..., None, :, :]).sum(-2)
+
+
 def covariance3d(log_scales, quats):
     """Sigma = R S S^T R^T, (..., 3, 3)."""
     R = quat_to_rotmat(quats)
     S = jnp.exp(log_scales)
     RS = R * S[..., None, :]
-    return RS @ jnp.swapaxes(RS, -1, -2)
+    return small_matmul(RS, jnp.swapaxes(RS, -1, -2))

@@ -38,6 +38,7 @@ def _filter2d(img, win):
     y = lax.conv_general_dilated(
         x, w, window_strides=(1, 1), padding=[(k // 2, k // 2)] * 2,
         dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        precision=lax.Precision.HIGHEST,    # f32, not TPU bf16 passes
     )
     return y[:, 0].transpose(1, 2, 0)
 

@@ -271,7 +271,8 @@ class TimestepData:
 
 def prepare_timestep(ds: GSDataset, cams: Camera, grid: TileGrid, *,
                      t: float = 0.0, seed: int = 0, n_parts: int = 2,
-                     capacity: int, K: int = 48, use_ghost: bool = True,
+                     capacity: int, K: int = 48, impl: str = "auto",
+                     use_ghost: bool = True,
                      use_mask: bool = True) -> TimestepData:
     """Host-side ingest for ONE timestep of the timeseries driver:
     extraction -> partition (+ghosts) -> fresh equal-capacity (P, N) init
@@ -304,7 +305,8 @@ def prepare_timestep(ds: GSDataset, cams: Camera, grid: TileGrid, *,
     gts, masks = [], []
     for pd in parts:
         part_gt, part_cov = render_views(
-            gt_gaussians(pd.points, pd.colors), cams, grid, K=K, bg=0.0)
+            gt_gaussians(pd.points, pd.colors), cams, grid, K=K, impl=impl,
+            bg=0.0)
         gts.append(part_gt)
         if use_mask:
             masks.append(coverage_masks(part_cov))

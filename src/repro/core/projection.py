@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.cameras import Camera
-from repro.core.gaussians import Gaussians, covariance3d
+from repro.core.gaussians import Gaussians, covariance3d, small_matmul
 
 # anti-aliasing dilation as in 3D-GS reference (0.3 px)
 COV2D_DILATE = 0.3
@@ -39,7 +39,7 @@ def project(g: Gaussians, cam: Camera, *, near: float = 0.05,
     """Project all gaussians for one camera. Fully vectorised over leading dims."""
     R = cam.view[:3, :3]
     t = cam.view[:3, 3]
-    p_cam = g.means @ R.T + t                     # (..., 3), camera looks +z
+    p_cam = (g.means[..., None, :] * R).sum(-1) + t   # (..., 3), looks +z
     x = p_cam[..., 0]
     y = p_cam[..., 1]
     z = p_cam[..., 2]
@@ -57,8 +57,9 @@ def project(g: Gaussians, cam: Camera, *, near: float = 0.05,
         axis=-2,
     )                                             # (..., 2, 3)
     cov3 = covariance3d(g.log_scales, g.quats)    # (..., 3, 3)
-    T = J @ R                                     # (..., 2, 3)
-    cov2 = T @ cov3 @ jnp.swapaxes(T, -1, -2)     # (..., 2, 2)
+    T = small_matmul(J, R)                        # (..., 2, 3)
+    cov2 = small_matmul(small_matmul(T, cov3),
+                        jnp.swapaxes(T, -1, -2))  # (..., 2, 2)
     a = cov2[..., 0, 0] + COV2D_DILATE
     b = cov2[..., 0, 1]
     c = cov2[..., 1, 1] + COV2D_DILATE

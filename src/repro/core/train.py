@@ -368,7 +368,7 @@ def densify_and_prune(g: Gaussians, opt: GSOptState, key, cfg: GSTrainCfg,
     eps = jax.random.normal(key, (M, 3))
     from repro.core.gaussians import quat_to_rotmat
     R = quat_to_rotmat(g.quats[src])
-    offset = jnp.einsum("nij,nj->ni", R, jnp.exp(g.log_scales[src]) * eps)
+    offset = (R * (jnp.exp(g.log_scales[src]) * eps)[:, None, :]).sum(-1)
     offset = jnp.where(src_split[:, None], offset, 0.0)
     shrink = jnp.where(src_split[:, None],
                        jnp.log(cfg.split_shrink), 0.0)

@@ -42,19 +42,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 ALPHA_MAX = 0.99
 ALPHA_MIN = 1.0 / 255.0
 
 
 def _pixel_grids(origin_x, origin_y, th: int, tw: int):
-    px = origin_x + 0.5 + lax.broadcasted_iota(jnp.float32, (th, tw), 1)
-    py = origin_y + 0.5 + lax.broadcasted_iota(jnp.float32, (th, tw), 0)
-    return px, py
+    # integer iota cast to f32: the TPU lowering only builds integer iotas
+    col = lax.broadcasted_iota(jnp.int32, (th, tw), 1).astype(jnp.float32)
+    row = lax.broadcasted_iota(jnp.int32, (th, tw), 0).astype(jnp.float32)
+    return origin_x + 0.5 + col, origin_y + 0.5 + row
 
 
 def _alpha_terms(f, px, py):
-    """Shared fwd/bwd per-splat math. f: (F,) feature row."""
+    """Shared fwd/bwd per-splat math. f: indexable feature row (f[j] is a
+    scalar — read straight from the SMEM feature block inside the kernels)."""
     dx = px - f[0]
     dy = py - f[1]
     sigma = 0.5 * (f[2] * dx * dx + f[4] * dy * dy) + f[3] * dx * dy
@@ -66,18 +69,50 @@ def _alpha_terms(f, px, py):
     return dx, dy, sigma, g, a_g, alpha, live
 
 
+class _Row:
+    """Splat k's feature row as lazy scalar reads from the (1, K, F) SMEM
+    block: ``_Row(ref, k)[j]`` is one scalar load, so the loop never
+    dynamic-slices a loaded vector (which has no TPU lowering)."""
+
+    def __init__(self, ref, k):
+        self.ref, self.k = ref, k
+
+    def __getitem__(self, j):
+        return self.ref[0, self.k, j]
+
+
+def _in_specs(K: int, F: int, n_planes: int, th: int, tw: int):
+    """Block specs shared by fwd/bwd: the per-tile feature list and origin
+    live in SMEM (scalar reads, broadcast into the VREG planes); the
+    (4, th, tw) image planes stream through VMEM."""
+    specs = [
+        pl.BlockSpec((1, K, F), lambda t: (t, 0, 0),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, 1, 2), lambda t: (t, 0, 0),
+                     memory_space=pltpu.SMEM),
+    ]
+    return specs + [pl.BlockSpec((1, 4, th, tw), lambda t: (t, 0, 0, 0))
+                    for _ in range(n_planes)]
+
+
+def _origin_blocks(origins):
+    """(T, 2) -> (T, 1, 2): a (1, 1, 2) block spans the array's last two
+    dims, which the TPU lowering requires of a block that is not
+    (8, 128)-aligned."""
+    return origins.astype(jnp.float32).reshape(-1, 1, 2)
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(feat_ref, origin_ref, out_ref, *, K: int, th: int, tw: int):
-    feats = feat_ref[0]                      # (K, F) -> registers
-    px, py = _pixel_grids(origin_ref[0, 0], origin_ref[0, 1], th, tw)
+    px, py = _pixel_grids(origin_ref[0, 0, 0], origin_ref[0, 0, 1], th, tw)
 
     def body(k, carry):
         trans, r, g, b = carry
-        f = lax.dynamic_index_in_dim(feats, k, 0, keepdims=False)
+        f = _Row(feat_ref, k)
         *_, alpha, _ = _alpha_terms(f, px, py)
         w = trans * alpha
         return (trans * (1.0 - alpha),
@@ -100,14 +135,11 @@ def rasterize_fwd(feats, origins, *, tile_h: int, tile_w: int,
     return pl.pallas_call(
         kernel,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, K, F), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, 2), lambda t: (t, 0)),
-        ],
+        in_specs=_in_specs(K, F, 0, tile_h, tile_w),
         out_specs=pl.BlockSpec((1, 4, tile_h, tile_w), lambda t: (t, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, 4, tile_h, tile_w), jnp.float32),
         interpret=interpret,
-    )(feats.astype(jnp.float32), origins.astype(jnp.float32))
+    )(feats.astype(jnp.float32), _origin_blocks(origins))
 
 
 # ---------------------------------------------------------------------------
@@ -116,17 +148,16 @@ def rasterize_fwd(feats, origins, *, tile_h: int, tile_w: int,
 
 
 def _bwd_kernel(feat_ref, origin_ref, out_ref, gout_ref, gfeat_ref,
-                *, K: int, th: int, tw: int):
-    feats = feat_ref[0]                       # (K, F)
-    px, py = _pixel_grids(origin_ref[0, 0], origin_ref[0, 1], th, tw)
+                *, K: int, F: int, th: int, tw: int):
+    px, py = _pixel_grids(origin_ref[0, 0, 0], origin_ref[0, 0, 1], th, tw)
     c_r, c_g, c_b = out_ref[0, 0], out_ref[0, 1], out_ref[0, 2]
     t_final = 1.0 - out_ref[0, 3]
     g_r, g_g, g_b, g_cov = (gout_ref[0, 0], gout_ref[0, 1],
                             gout_ref[0, 2], gout_ref[0, 3])
 
     def body(k, carry):
-        trans, pr, pg, pb, gf = carry
-        f = lax.dynamic_index_in_dim(feats, k, 0, keepdims=False)
+        trans, pr, pg, pb = carry
+        f = _Row(feat_ref, k)
         dx, dy, sigma, g, a_g, alpha, live = _alpha_terms(f, px, py)
         w = trans * alpha
         pr = pr + w * f[5]
@@ -142,7 +173,7 @@ def _bwd_kernel(feat_ref, origin_ref, out_ref, gout_ref, gfeat_ref,
         mask = live & (a_g < ALPHA_MAX)
         g_ag = jnp.where(mask, g_alpha, 0.0)
         g_sigma = jnp.where(sigma > 0.0, -a_g * g_ag, 0.0)
-        row = jnp.stack([
+        row = (
             jnp.sum(-(f[2] * dx + f[3] * dy) * g_sigma),     # d/d mean_x
             jnp.sum(-(f[4] * dy + f[3] * dx) * g_sigma),     # d/d mean_y
             jnp.sum(0.5 * dx * dx * g_sigma),                # d/d conic A
@@ -152,39 +183,33 @@ def _bwd_kernel(feat_ref, origin_ref, out_ref, gout_ref, gfeat_ref,
             jnp.sum(g_g * w),                                # d/d g
             jnp.sum(g_b * w),                                # d/d b
             jnp.sum(g_ag * g),                               # d/d alpha
-        ])
-        row = jnp.concatenate(
-            [row, jnp.zeros((feats.shape[1] - 9,), jnp.float32)]
         )
-        gf = lax.dynamic_update_index_in_dim(gf, row, k, 0)
-        return (trans * denom, pr, pg, pb, gf)
+        # one scalar store per feature column into the SMEM gradient block;
+        # the unused tail columns are written as exact zeros
+        for j in range(F):
+            gfeat_ref[0, k, j] = row[j] if j < len(row) else jnp.float32(0)
+        return (trans * denom, pr, pg, pb)
 
     zero = jnp.zeros((th, tw), jnp.float32)
-    init = (jnp.ones((th, tw), jnp.float32), zero, zero, zero,
-            jnp.zeros(feats.shape, jnp.float32))
-    *_, gf = lax.fori_loop(0, K, body, init)
-    gfeat_ref[0] = gf
+    lax.fori_loop(0, K, body,
+                  (jnp.ones((th, tw), jnp.float32), zero, zero, zero))
 
 
 def rasterize_bwd(feats, origins, out, gout, *, tile_h: int, tile_w: int,
                   interpret: bool = False):
     T, K, F = feats.shape
-    kernel = functools.partial(_bwd_kernel, K=K, th=tile_h, tw=tile_w)
+    kernel = functools.partial(_bwd_kernel, K=K, F=F, th=tile_h, tw=tile_w)
     return pl.pallas_call(
         kernel,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, K, F), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, 2), lambda t: (t, 0)),
-            pl.BlockSpec((1, 4, tile_h, tile_w), lambda t: (t, 0, 0, 0)),
-            pl.BlockSpec((1, 4, tile_h, tile_w), lambda t: (t, 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, K, F), lambda t: (t, 0, 0)),
+        in_specs=_in_specs(K, F, 2, tile_h, tile_w),
+        out_specs=pl.BlockSpec((1, K, F), lambda t: (t, 0, 0),
+                               memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((T, K, F), jnp.float32),
         interpret=interpret,
     )(
         feats.astype(jnp.float32),
-        origins.astype(jnp.float32),
+        _origin_blocks(origins),
         out.astype(jnp.float32),
         gout.astype(jnp.float32),
     )

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import all_arch_ids, get_spec
 from repro.configs.gs_datasets import FULL as GS_FULL
 from repro.launch import hlo_analysis
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import make_mesh, make_production_mesh
 from repro.models.params import param_shardings, param_specs
 from repro.models.steps import (
     SHAPES,
@@ -70,10 +70,10 @@ def make_meshes(which: str):
             out["multi"] = make_production_mesh(multi_pod=True)
     else:  # reduced test meshes (REPRO_DRYRUN_DEVICES)
         if which in ("single", "both"):
-            out["single"] = jax.make_mesh((2, n // 2), ("data", "model"))
+            out["single"] = make_mesh((2, n // 2), ("data", "model"))
         if which in ("multi", "both"):
-            out["multi"] = jax.make_mesh((2, 2, n // 4),
-                                         ("pod", "data", "model"))
+            out["multi"] = make_mesh((2, 2, n // 4),
+                                     ("pod", "data", "model"))
     return out
 
 

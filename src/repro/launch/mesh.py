@@ -1,26 +1,48 @@
-"""Production mesh construction.
+"""Mesh construction: the one place this repo builds a ``jax.sharding.Mesh``.
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run forces 512
 host devices while tests/benches must see 1 (assignment, MULTI-POD DRY-RUN
 step 1).
+
+Every axis is ``AxisType.Auto``.  Since jax 0.8 ``jax.make_mesh`` defaults
+to Explicit axes, under which sharding propagation is part of the type
+system: the vmapped densify's fixed-size ``jnp.nonzero`` lowers to a scatter
+whose sharding check then fails ("Resource axis: part of
+PartitionSpec('part',) is not found in mesh").  The trainer is written for
+Auto axes (shard_map bodies + jit in/out shardings), so meshes are built
+here and ``check_auto_mesh`` refuses anything else at the trainer's entry.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """Mesh of ``shape`` over named ``axes``, every axis Auto.
+
+    ``devices`` (default: ``jax.devices()``) pins the device set, e.g. a
+    1x1 mesh on ``jax.devices()[:1]`` next to a 2x2 mesh in one process."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def check_auto_mesh(mesh):
+    """Raise a clear ValueError for a mesh with non-Auto axes."""
+    types = getattr(mesh, "axis_types", None) or ()
+    bad = [n for n, t in zip(mesh.axis_names, types) if t != AxisType.Auto]
+    if bad:
+        raise ValueError(
+            f"mesh axes {bad} are not AxisType.Auto (got {types}); the GS "
+            "trainer needs Auto axes — build the mesh with "
+            "repro.launch.mesh.make_mesh (jax.make_mesh defaults to "
+            "Explicit axes since jax 0.8)")
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (elastic restore targets, tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
-def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh(shape, axes)

@@ -32,6 +32,7 @@ from repro.launch import hlo_analysis as H
 from repro.launch.dryrun import (lower_gs_cell, lower_gs_train_cell,
                                  lower_lm_cell, make_meshes)
 from repro.configs import get_spec
+from repro.launch.mesh import make_mesh
 
 OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 
@@ -92,7 +93,7 @@ def main():
     if args.gs_train:
         n = len(jax.devices())
         v = math.gcd(max(1, args.gs_view_batch), n)
-        mesh = jax.make_mesh((n // v, v), ("part", "view"))
+        mesh = make_mesh((n // v, v), ("part", "view"))
         lowered, meta = lower_gs_train_cell(
             args.gs_train, mesh, res=args.gs_res, n_parts=args.gs_parts,
             view_batch=args.gs_view_batch)

@@ -27,7 +27,7 @@ import os
 import time
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--ckpt-dir", default="checkpoints",
                     help="a launch/train.py --gs checkpoint tree (must "
@@ -45,13 +45,18 @@ def main():
     ap.add_argument("--far", type=float, default=5.0,
                     help="far orbit radius (same units) — drives LOD rung "
                          "selection")
-    ap.add_argument("--impl", default="auto")
+    ap.add_argument("--impl", default="auto",
+                    choices=["auto", "ref", "interpret", "pallas"])
+    ap.add_argument("--full", action="store_true",
+                    help="serve a --full checkpoint on a TPU with the Pallas "
+                         "kernels; exits with an error off-TPU or for a "
+                         "checkpoint not trained at the (8, 128) tile")
     ap.add_argument("--telemetry-json", default=None,
                     help="write the serving telemetry + per-pass stats "
                          "as JSON")
     ap.add_argument("--host-devices", type=int, default=0,
                     help="force N host CPU devices (before jax import)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.host_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices} "
@@ -63,11 +68,24 @@ def main():
 
     from repro.core.cameras import Camera, orbital_rig
     from repro.core.serving import GSRenderServer
+    from repro.launch.device import (FULL_IMPL, FULL_TILE,
+                                     enable_compile_cache, require_tpu)
 
+    enable_compile_cache()
+    if args.full:
+        require_tpu("--full")
+        if args.impl not in ("auto", FULL_IMPL):
+            raise SystemExit(f"--full serves impl={FULL_IMPL!r}; "
+                             f"--impl {args.impl} is a CPU setting")
+        args.impl = FULL_IMPL
     server, extra = GSRenderServer.from_checkpoint(
         args.ckpt_dir, impl=args.impl, max_batch=args.max_batch,
         cache_entries=args.cache_entries)
     meta = extra.get("scene", {})
+    tile = (server.grid.tile_h, server.grid.tile_w)
+    if args.full and tile != FULL_TILE:
+        raise SystemExit(f"--full serves {FULL_TILE} tiles; this checkpoint "
+                         f"was trained at {tile} (train it with --full)")
     g0 = server.ladder[0]
     print(f"[serve-gs] devices={len(jax.devices())} "
           f"model={int(np.asarray(g0.active).sum()):,} live splats "

@@ -80,22 +80,140 @@ def run_lm(args):
     print("[train] done")
 
 
-def run_gs(args):
+def gs_train_cfg(args):
+    """GSTrainCfg from the CLI flags.  ``--full`` is the paper's sizes on
+    the chip: it fails off-TPU and pins the (8, 128) tile + Pallas
+    kernels; otherwise the CPU tile and ``--impl`` apply."""
+    from repro.core.train import GSTrainCfg
+    from repro.launch.device import (CPU_TILE, FULL_IMPL, FULL_TILE,
+                                     require_tpu)
+
+    if args.full:
+        require_tpu("--full")
+        if args.impl not in ("auto", FULL_IMPL):
+            raise SystemExit(f"--full runs impl={FULL_IMPL!r}; "
+                             f"--impl {args.impl} is a CPU setting")
+        (th, tw), impl = FULL_TILE, FULL_IMPL
+    else:
+        (th, tw), impl = CPU_TILE, args.impl
+    return GSTrainCfg(view_batch=args.view_batch or 1,
+                      exchange=args.exchange,
+                      exchange_budget=args.exchange_budget,
+                      dtype_policy=args.dtype_policy,
+                      grad_compress=args.grad_compress,
+                      tile_h=th, tile_w=tw, impl=impl)
+
+
+def gs_mesh(args, cfg, n_views: int, devices=None):
+    """The ("part", "view") mesh: ``--mesh PxV`` or the widest "view" axis
+    the EFFECTIVE minibatch supports (the driver clamps view_batch to the
+    view count), the rest going to "part"."""
+    import jax
+
+    from repro.launch.mesh import make_mesh
+
+    devices = jax.devices() if devices is None else list(devices)
+    n_dev = len(devices)
+    if args.mesh:
+        p, v = (int(x) for x in args.mesh.lower().split("x"))
+        if p * v != n_dev:
+            raise SystemExit(f"--mesh {args.mesh} needs {p * v} devices, "
+                             f"have {n_dev} (try --host-devices {p * v})")
+    else:
+        v = math.gcd(max(1, min(cfg.view_batch, n_views)), n_dev)
+        p = n_dev // v
+    return make_mesh((p, v), ("part", "view"), devices=devices)
+
+
+def prepare_gs(args, cfg, ds, n_views: int, part_shards: int):
+    """Host + device prep of one GS scene: isosurface extraction ->
+    partition (+ ghost halo) -> equal-capacity (P, N) stack (capacity a
+    multiple of ``part_shards``) -> per-partition GT renders + coverage
+    masks.  -> SimpleNamespace of everything the trainer and eval need."""
+    import types
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.configs.gs_datasets import get_gs_dataset
-    from repro.core import merge as merge_mod
-    from repro.core import metrics
     from repro.core.cameras import orbital_rig
-    from repro.core.distributed import fit_partitions
     from repro.core.partition import partition_points
     from repro.core.pipeline import (build_scene, coverage_masks,
                                      gt_gaussians, init_partition_gaussians,
                                      render_views)
     from repro.core.tiling import TileGrid
-    from repro.core.train import GSTrainCfg
+
+    points, colors, extent = build_scene(ds, args.seed)
+    center = 0.5 * (points.max(0) + points.min(0))
+    radius = 1.6 * extent / 2 + 1e-3
+    W = H = args.resolution
+    grid = TileGrid(W, H, cfg.tile_h, cfg.tile_w)
+    cams = orbital_rig(n_views, center, radius, width=W, height=H)
+
+    # partition (+ghost halo) -> equal-capacity batched (P, N) layout
+    ghost_w = ds.ghost_frac * extent if not args.no_ghost else 0.0
+    parts, _ = partition_points(points, colors, args.parts,
+                                ghost_width=ghost_w)
+    base = max(len(pd.points) for pd in parts)
+    cap = int(base * ds.capacity_factor) if args.densify_every else base
+    cap = -(-cap // part_shards) * part_shards  # "part"-shardable capacity
+    g = jax.tree.map(lambda *xs: jnp.stack(xs),
+                     *[init_partition_gaussians(pd, capacity=cap)
+                       for pd in parts])
+
+    # per-partition GT renders of own (+ghost) data and coverage masks.
+    # Training GT is rendered at bg=0: the distributed tile loss compares
+    # RAW premultiplied color tiles (no background composite), so a
+    # white-composited target would carry a bias the prediction can never
+    # produce (the driver parity tests pin the same convention); the
+    # white-background renders stay eval-only.
+    gts, masks = [], []
+    for pd in parts:
+        part_gt, part_cov = render_views(
+            gt_gaussians(pd.points, pd.colors), cams, grid, K=cfg.K,
+            impl=cfg.impl, bg=0.0)
+        gts.append(part_gt)
+        if not args.no_mask:
+            masks.append(coverage_masks(part_cov))
+    return types.SimpleNamespace(
+        points=points, colors=colors, extent=extent, center=center,
+        radius=radius, grid=grid, cams=cams, parts=parts, g=g,
+        gts=jnp.asarray(np.stack(gts)),
+        masks=None if args.no_mask else jnp.asarray(np.stack(masks)))
+
+
+def _gs_defaults(args, ds):
+    """Resolve the dataset-dependent flag defaults in place."""
+    if args.resolution is None:
+        args.resolution = ds.resolutions[0] if args.full else 64
+    if args.views is None:
+        args.views = ds.n_views
+
+
+def setup_gs(args):
+    """Config, mesh and prepared scene of a ``--gs`` run -> (cfg, mesh,
+    scene); see ``prepare_gs``."""
+    from repro.configs.gs_datasets import get_gs_dataset
+
+    cfg = gs_train_cfg(args)
+    ds = get_gs_dataset(args.dataset, "full" if args.full else "cpu")
+    _gs_defaults(args, ds)
+    mesh = gs_mesh(args, cfg, args.views)
+    return cfg, mesh, prepare_gs(args, cfg, ds, args.views,
+                                 mesh.devices.shape[0])
+
+
+def run_gs(args):
+    """The GS lifecycle -> {cfg, grid, cams, losses, merged, n_live,
+    n_init} for callers that check the run (chip_smoke.py)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import merge as merge_mod
+    from repro.core import metrics
+    from repro.core.distributed import fit_partitions
+    from repro.core.pipeline import gt_gaussians, render_views
     from repro.runtime import CheckpointManager
 
     if args.smoke:
@@ -106,7 +224,7 @@ def run_gs(args):
         # the resume path with a second invocation.
         args.dataset = "sphere_shell"
         args.parts = 2
-        args.resolution = min(args.resolution, 32)
+        args.resolution = min(args.resolution or 32, 32)
         args.views = args.views or 4
         args.view_batch = args.view_batch or 2
         if args.densify_every == 0:
@@ -114,61 +232,10 @@ def run_gs(args):
         if args.ckpt_every == 0:
             args.ckpt_every = 2
 
-    cfg = GSTrainCfg(view_batch=args.view_batch or 1,
-                     exchange=args.exchange,
-                     exchange_budget=args.exchange_budget,
-                     dtype_policy=args.dtype_policy,
-                     grad_compress=args.grad_compress)
-    ds = get_gs_dataset(args.dataset, "full" if args.full else "cpu")
-    n_views = args.views or ds.n_views
-    points, colors, extent = build_scene(ds, args.seed)
-    center = 0.5 * (points.max(0) + points.min(0))
-    radius = 1.6 * extent / 2 + 1e-3
-    W = H = args.resolution
-    grid = TileGrid(W, H, cfg.tile_h, cfg.tile_w)
-    cams = orbital_rig(n_views, center, radius, width=W, height=H)
-
-    # partition (+ ghost halo) -> equal-capacity batched (P, N) layout
-    ghost_w = ds.ghost_frac * extent if not args.no_ghost else 0.0
-    parts, _ = partition_points(points, colors, args.parts,
-                                ghost_width=ghost_w)
-
-    n_dev = len(jax.devices())
-    if args.mesh:
-        p, v = (int(x) for x in args.mesh.lower().split("x"))
-        if p * v != n_dev:
-            raise SystemExit(f"--mesh {args.mesh} needs {p * v} devices, "
-                             f"have {n_dev} (try --host-devices {p * v})")
-    else:
-        # widest "view" axis the EFFECTIVE minibatch supports (the driver
-        # clamps view_batch to the view count); the rest go to "part"
-        v = math.gcd(max(1, min(cfg.view_batch, n_views)), n_dev)
-        p = n_dev // v
-    mesh = jax.make_mesh((p, v), ("part", "view"))
-
-    base = max(len(pd.points) for pd in parts)
-    cap = int(base * ds.capacity_factor) if args.densify_every else base
-    cap = -(-cap // p) * p          # "part"-shardable capacity
-    g = jax.tree.map(lambda *xs: jnp.stack(xs),
-                     *[init_partition_gaussians(pd, capacity=cap)
-                       for pd in parts])
-
-    # per-partition GT renders of own (+ghost) data and coverage masks.
-    # Training GT is rendered at bg=0: the distributed tile loss compares
-    # RAW premultiplied color tiles (no background composite), so a
-    # white-composited target would carry a bias the prediction can never
-    # produce (the driver parity tests pin the same convention); the
-    # white-background renders stay eval-only below.
-    gts, masks = [], []
-    for pd in parts:
-        part_gt, part_cov = render_views(
-            gt_gaussians(pd.points, pd.colors), cams, grid, K=cfg.K,
-            bg=0.0)
-        gts.append(part_gt)
-        if not args.no_mask:
-            masks.append(coverage_masks(part_cov))
-    gts = jnp.asarray(np.stack(gts))
-    masks = None if args.no_mask else jnp.asarray(np.stack(masks))
+    cfg, mesh, sc = setup_gs(args)
+    n_views = args.views
+    p, v = mesh.devices.shape
+    grid, cams = sc.grid, sc.cams
 
     kt = cfg.resolved_k_tiers()
     table = "exchange" if cfg.exchange else "all-gather"
@@ -176,9 +243,10 @@ def run_gs(args):
         table += f"(budget={cfg.exchange_budget})"
     print(f"[train-gs] dataset={args.dataset} parts={args.parts} "
           f"res={args.resolution} views={n_views} mesh={p}x{v} "
-          f"({n_dev} devices) ghost={not args.no_ghost} "
+          f"({mesh.devices.size} devices) ghost={not args.no_ghost} "
           f"mask={not args.no_mask} table={table} raster="
           f"{'tiered ' + str(kt) if kt else 'dense K=' + str(cfg.assign_K)} "
+          f"tile={cfg.tile_h}x{cfg.tile_w} impl={cfg.impl} "
           f"dtype={cfg.dtype_policy} grad-compress={cfg.grad_compress}")
 
     ckpt = CheckpointManager(args.ckpt_dir, keep=2)
@@ -189,11 +257,11 @@ def run_gs(args):
     sched = cfg.tier_schedule()
     t0 = time.perf_counter()
     g1, _, losses = fit_partitions(
-        g, cams, gts, masks, cfg, mesh=mesh, steps=args.steps,
-        extent=extent, key=jax.random.PRNGKey(args.seed),
+        sc.g, cams, sc.gts, sc.masks, cfg, mesh=mesh, steps=args.steps,
+        extent=sc.extent, key=jax.random.PRNGKey(args.seed),
         densify_every=args.densify_every, densify_from=args.densify_from,
-        grid=grid, schedule=sched, ckpt=ckpt, ckpt_every=args.ckpt_every,
-        rebalance_every=args.rebalance_every,
+        grid=grid, schedule=sched, impl=cfg.impl, ckpt=ckpt,
+        ckpt_every=args.ckpt_every, rebalance_every=args.rebalance_every,
         log_every=args.log_every)
     train_s = time.perf_counter() - t0
     # a restored checkpoint may already be PAST --steps; label everything
@@ -222,18 +290,18 @@ def run_gs(args):
                    extra={"dataset": args.dataset})
 
     merged = merge_mod.merge_partitions(part_list,
-                                        [pd.part_id for pd in parts])
-    gt_imgs, _ = render_views(gt_gaussians(points, colors), cams, grid,
-                              K=cfg.K)
-    renders, _ = render_views(merged, cams, grid, K=cfg.K)
+                                        [pd.part_id for pd in sc.parts])
+    gt_imgs, _ = render_views(gt_gaussians(sc.points, sc.colors), cams,
+                              grid, K=cfg.K, impl=cfg.impl)
+    renders, _ = render_views(merged, cams, grid, K=cfg.K, impl=cfg.impl)
     ps = float(np.mean([metrics.psnr(jnp.asarray(renders[i]),
                                      jnp.asarray(gt_imgs[i]))
                         for i in range(n_views)]))
     ss = float(np.mean([metrics.ssim(jnp.asarray(renders[i]),
                                      jnp.asarray(gt_imgs[i]))
                         for i in range(n_views)]))
-    print(f"[train-gs] PSNR {ps:.2f}  SSIM {ss:.4f}  "
-          f"gaussians {int(np.asarray(merged.active).sum()):,}")
+    n_live = int(np.asarray(merged.active).sum())
+    print(f"[train-gs] PSNR {ps:.2f}  SSIM {ss:.4f}  gaussians {n_live:,}")
 
     # train->serve handoff: the MERGED model as its own checkpoint (the
     # per-partition tree above is the recovery path; the serving driver
@@ -243,9 +311,10 @@ def run_gs(args):
     mckpt = CheckpointManager(os.path.join(args.ckpt_dir, "merged"), keep=2)
     merged_extra = {"scene": {
         "dataset": args.dataset, "resolution": args.resolution,
-        "center": [float(c) for c in center], "radius": float(radius),
-        "extent": float(extent), "n_views": int(n_views), "K": int(cfg.K),
-        "tile_h": int(cfg.tile_h), "tile_w": int(cfg.tile_w),
+        "center": [float(c) for c in sc.center], "radius": float(sc.radius),
+        "extent": float(sc.extent), "n_views": int(n_views),
+        "K": int(cfg.K), "tile_h": int(cfg.tile_h),
+        "tile_w": int(cfg.tile_w),
     }}
     merged_save = merged
     if args.ckpt_quantize == "int8":
@@ -260,6 +329,9 @@ def run_gs(args):
     np.save(os.path.join(args.ckpt_dir, "render_final.npy"), renders)
     print(f"[train-gs] merged checkpoint (step {done}) + final render "
           f"saved under {args.ckpt_dir}")
+    return {"cfg": cfg, "grid": grid, "cams": cams, "losses": losses,
+            "merged": merged, "n_live": n_live,
+            "n_init": int(np.asarray(sc.g.active).sum())}
 
 
 def run_gs_timeseries(args):
@@ -284,13 +356,13 @@ def run_gs_timeseries(args):
                                      gt_gaussians, prepare_timestep,
                                      render_views)
     from repro.core.tiling import TileGrid
-    from repro.core.train import GSTrainCfg, init_opt
+    from repro.core.train import init_opt
     from repro.runtime import CheckpointManager
 
     if args.smoke:
         args.dataset = "sphere_shell"
         args.parts = 2
-        args.resolution = min(args.resolution, 32)
+        args.resolution = min(args.resolution or 32, 32)
         args.views = args.views or 4
         args.view_batch = args.view_batch or 2
         args.timesteps = min(args.timesteps, 2)
@@ -299,13 +371,10 @@ def run_gs_timeseries(args):
         if args.densify_cap is None:
             args.densify_cap = 4096
 
-    cfg = GSTrainCfg(view_batch=args.view_batch or 1,
-                     exchange=args.exchange,
-                     exchange_budget=args.exchange_budget,
-                     dtype_policy=args.dtype_policy,
-                     grad_compress=args.grad_compress)
+    cfg = gs_train_cfg(args)
     ds = get_gs_dataset(args.dataset, "full" if args.full else "cpu")
-    n_views = args.views or ds.n_views
+    _gs_defaults(args, ds)
+    n_views = args.views
     T, S = args.timesteps, args.steps
 
     # series-fixed frame: rig, grid, capacity all come from the t=0 scene
@@ -318,16 +387,9 @@ def run_gs_timeseries(args):
     grid = TileGrid(W, H, cfg.tile_h, cfg.tile_w)
     cams = orbital_rig(n_views, center, radius, width=W, height=H)
 
-    n_dev = len(jax.devices())
-    if args.mesh:
-        p, v = (int(x) for x in args.mesh.lower().split("x"))
-        if p * v != n_dev:
-            raise SystemExit(f"--mesh {args.mesh} needs {p * v} devices, "
-                             f"have {n_dev} (try --host-devices {p * v})")
-    else:
-        v = math.gcd(max(1, min(cfg.view_batch, n_views)), n_dev)
-        p = n_dev // v
-    mesh = jax.make_mesh((p, v), ("part", "view"))
+    mesh = gs_mesh(args, cfg, n_views)
+    p, v = mesh.devices.shape
+    n_dev = mesh.devices.size
 
     from repro.core.partition import partition_points
     parts0, _ = partition_points(
@@ -360,7 +422,7 @@ def run_gs_timeseries(args):
     def prep(t_idx):
         return prepare_timestep(
             ds, cams, grid, t=t_idx * args.dt, seed=args.seed,
-            n_parts=args.parts, capacity=cap, K=cfg.K,
+            n_parts=args.parts, capacity=cap, K=cfg.K, impl=cfg.impl,
             use_ghost=not args.no_ghost, use_mask=not args.no_mask)
 
     warm = None          # (host state tree, extra, global step)
@@ -402,7 +464,7 @@ def run_gs_timeseries(args):
                 # continuous (or disk-resumed) run would have consumed, so
                 # a repeated static timestep is bit-on the resume oracle
                 densify_from=args.densify_from, grid=grid,
-                schedule=sched, exchange_schedule=ex,
+                schedule=sched, exchange_schedule=ex, impl=cfg.impl,
                 rebalance_every=args.rebalance_every,
                 log_every=args.log_every, warm_start=warm,
                 densify_cap=args.densify_cap)
@@ -451,8 +513,8 @@ def run_gs_timeseries(args):
     merged = merge_mod.merge_partitions(part_list,
                                         [pd.part_id for pd in td.parts])
     gt_imgs, _ = render_views(gt_gaussians(td.points, td.colors), cams,
-                              grid, K=cfg.K)
-    renders, _ = render_views(merged, cams, grid, K=cfg.K)
+                              grid, K=cfg.K, impl=cfg.impl)
+    renders, _ = render_views(merged, cams, grid, K=cfg.K, impl=cfg.impl)
     ps = float(np.mean([metrics.psnr(jnp.asarray(renders[i]),
                                      jnp.asarray(gt_imgs[i]))
                         for i in range(n_views)]))
@@ -483,7 +545,7 @@ def run_gs_timeseries(args):
           f"saved under {args.ckpt_dir}")
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--gs", action="store_true")
     # LM
@@ -500,9 +562,17 @@ def main():
                     choices=["none", "bf16", "int8"])
     # GS
     ap.add_argument("--dataset", default="sphere_shell")
-    ap.add_argument("--full", action="store_true")
+    ap.add_argument("--full", action="store_true",
+                    help="the paper's sizes on a TPU: (8, 128) tiles and the "
+                         "Pallas kernels; exits with an error off-TPU")
+    ap.add_argument("--impl", default="auto",
+                    choices=["auto", "ref", "interpret", "pallas"],
+                    help="rasterizer without --full (auto: pallas on TPU, "
+                         "ref elsewhere)")
     ap.add_argument("--parts", type=int, default=2)
-    ap.add_argument("--resolution", type=int, default=64)
+    ap.add_argument("--resolution", type=int, default=None,
+                    help="image side (default: the dataset's first "
+                         "resolution with --full, else 64)")
     ap.add_argument("--views", type=int, default=None)
     ap.add_argument("--view-batch", type=int, default=None,
                     help="views per minibatch step (sharded over the mesh's "
@@ -567,11 +637,17 @@ def main():
                     help="force N host-backed CPU devices (applied BEFORE "
                          "jax import; lets the distributed GS driver run "
                          "its real multi-device mesh on one machine/CI)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main(argv=None):
+    args = parse_args(argv)
     if args.host_devices:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.host_devices} "
             + os.environ.get("XLA_FLAGS", ""))
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
     if args.gs and args.timeseries:
         run_gs_timeseries(args)
     elif args.gs:

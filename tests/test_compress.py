@@ -140,11 +140,12 @@ def test_int8_error_feedback_resets_at_timestep_boundary(tmp_path):
     changes the trajectory when it IS carried."""
     from repro.core.distributed import fit_partitions
     from repro.core.train import GSTrainCfg, init_opt
+    from repro.launch.mesh import make_mesh
     from repro.runtime import CheckpointManager
 
     cfg = GSTrainCfg(K=8, lambda_dssim=0.0, bg=0.0, view_batch=1,
                      lr_colors=5e-2, grad_compress="int8")
-    mesh = jax.make_mesh((1, 1), ("part", "view"))
+    mesh = make_mesh((1, 1), ("part", "view"))
     key = jax.random.PRNGKey(7)
 
     def run(**over):

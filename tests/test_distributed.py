@@ -36,6 +36,35 @@ def test_tile_view_batches_masks_none_excludes_grid_padding():
     _, mask_t2 = _tile_view_batches(jnp.asarray(gts), ones, grid)
     np.testing.assert_array_equal(mask_t, mask_t2)
 
+
+def test_trainer_refuses_explicit_mesh_axes():
+    """jax.make_mesh defaults to Explicit axes; the trainer needs Auto ones
+    and says so up front instead of failing inside densify's scatter."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.cameras import orbital_rig
+    from repro.core.distributed import fit_partitions, make_gs_train_step
+    from repro.core.gaussians import from_points
+    from repro.core.tiling import TileGrid
+    from repro.core.train import GSTrainCfg
+    from repro.launch.mesh import make_mesh
+
+    explicit = jax.make_mesh((1, 1), ("part", "view"),
+                             axis_types=(jax.sharding.AxisType.Explicit,) * 2)
+    cfg, grid = GSTrainCfg(K=8), TileGrid(16, 16, 8, 16)
+    with pytest.raises(ValueError, match="AxisType.Auto"):
+        make_gs_train_step(explicit, cfg, grid, 1.0)
+    g = jax.tree.map(lambda x: x[None],
+                     from_points(jnp.full((4, 3), 0.5), jnp.full((4, 3), 0.5)))
+    cams = orbital_rig(1, (0.5, 0.5, 0.5), 1.6, width=16, height=16)
+    gts = jnp.zeros((1, 1, 16, 16, 3))
+    with pytest.raises(ValueError, match="AxisType.Auto"):
+        fit_partitions(g, cams, gts, None, cfg, mesh=explicit, steps=1,
+                       extent=1.0, grid=grid)
+    auto = make_mesh((1, 1), ("part", "view"))
+    assert all(t == jax.sharding.AxisType.Auto for t in auto.axis_types)
+
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -54,8 +83,9 @@ from repro.core.render import render_tiles
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 Pn = 2
 N = 256                      # divisible by data axis
 res, K = 32, 16
@@ -202,8 +232,9 @@ from repro.core.render import render_tiles
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, GSOptState
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 Pn, N, res, K, V = 2, 256, 32, 16, 3
 grid = TileGrid(res, res, 8, 16)
 T = grid.n_tiles
@@ -328,6 +359,7 @@ from repro.core.render import render_tiles
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, GSOptState, group_lrs
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 
 Pn, N, res, K, V = 2, 256, 32, 16, 2
 grid = TileGrid(res, res, 8, 16)
@@ -349,8 +381,8 @@ ref = jnp.stack(ref)                                 # (V, P*T, 4, th, tw)
 gt = jnp.clip(ref[:, :, :3] + 0.05, 0, 1)
 mask = jnp.ones((V, Pn * T, grid.tile_h, grid.tile_w), bool)
 
-mesh2d = jax.make_mesh((2, 2), ("part", "view"))
-mesh1d = jax.make_mesh((2,), ("part",))
+mesh2d = make_mesh((2, 2), ("part", "view"))
+mesh1d = make_mesh((2,), ("part",))
 cfg = GSTrainCfg(K=K, lr_colors=5e-2)
 
 # ---- 2-D forward: view-sharded tiles/loss match the per-view reference,
@@ -516,12 +548,13 @@ from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, fit_partition
 from repro.data.isosurface import point_cloud_for
 from repro.runtime import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 N, res, V = 256, 32, 4
 pts, cols = point_cloud_for("sphere_shell", N)
 pts, cols = pts[:N], cols[:N]
 cams = orbital_rig(V, (0.5, 0.5, 0.5), 1.6, width=res, height=res)
-mesh = jax.make_mesh((2, 2), ("part", "view"))
+mesh = make_mesh((2, 2), ("part", "view"))
 grid = TileGrid(res, res, 8, 16)
 
 # GT rendered at bg=0: the distributed tile loss compares RAW premultiplied
@@ -534,14 +567,15 @@ g0 = from_points(jnp.asarray(pts), jnp.asarray(cols), capacity=N + 128,
                  opacity=0.7)
 g_b = jax.tree.map(lambda x: x[None], g0)           # (P=1, N) batched
 
-def check(tag, single, dist):
+def check(tag, single, dist, atol=None):
     gs_1, _, l1 = single
     gs_2, _, l2 = dist
     np.testing.assert_allclose(l1, l2, rtol=1e-5, atol=1e-6, err_msg=tag)
     for k, v in gs_1.trainable().items():
         np.testing.assert_allclose(
             np.asarray(v), np.asarray(getattr(gs_2, k))[0],
-            rtol=1e-6, atol=1e-6, err_msg=f"{tag}:{k}")
+            rtol=1e-6, atol=(atol or {}).get(k, 1e-6),
+            err_msg=f"{tag}:{k}")
     assert int(np.asarray(gs_1.active).sum()) \
         == int(np.asarray(gs_2.active).sum()), tag
     print(tag, [round(l, 5) for l in l2])
@@ -556,11 +590,17 @@ def check(tag, single, dist):
 cfg = GSTrainCfg(K=16, lambda_dssim=0.0, bg=0.0, view_batch=2,
                  lr_colors=5e-2, max_new=64, densify_grad_thresh=1e-9)
 kw = dict(steps=6, extent=1.0, densify_every=3, densify_from=0, grid=grid)
+# quats atol 3e-6: reduction order.  The "view" axis sums the two views'
+# gradients with a cross-device psum instead of inside one device; the
+# same lifecycle on a (2, 1) mesh is bit-exact, while (1, 2) and (2, 2)
+# both leave 1 of 1536 quats elements off by 2.1e-6 after 6 Adam steps
+# (every other element within 1e-6).
 check("TIERED-LIFECYCLE-PARITY",
       fit_partition(g0, cams, gts, masks, cfg, key=jax.random.PRNGKey(1),
                     **kw),
       fit_partitions(g_b, cams, gts[None], masks[None], cfg, mesh=mesh,
-                     key=jax.random.PRNGKey(1), **kw))
+                     key=jax.random.PRNGKey(1), **kw),
+      atol={"quats": 3e-6})
 
 # ---- dense escape hatch: same driver loop, no schedule ----
 cfg_d = GSTrainCfg(K=16, dense_k=16, lambda_dssim=0.0, bg=0.0,
@@ -790,6 +830,7 @@ from repro.core.gaussians import from_points
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, GSOptState
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 
 Pn, N, res, K, V = 2, 256, 32, 16, 2
 grid = TileGrid(res, res, 8, 16)
@@ -802,8 +843,8 @@ g_all = from_points(jnp.asarray(pts), jnp.asarray(cols), opacity=0.8)
 part = lambda i: jax.tree.map(lambda x: x[i * N:(i + 1) * N], g_all)
 g_b = jax.tree.map(lambda *xs: jnp.stack(xs), part(0), part(1))
 
-mesh2d = jax.make_mesh((2, 2), ("part", "view"))
-mesh1d = jax.make_mesh((4,), ("part",))
+mesh2d = make_mesh((2, 2), ("part", "view"))
+mesh1d = make_mesh((4,), ("part",))
 g_sh, opt_sh, b_sh = gs_shardings(mesh2d, views=V)
 g_dev = jax.device_put(g_b, g_sh)
 cam_dev = jax.device_put(cam_b, b_sh["cam"])
@@ -1050,6 +1091,7 @@ from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, init_opt
 from repro.data.isosurface import point_cloud_for
 from repro.runtime import CheckpointManager
+from repro.launch.mesh import make_mesh
 
 N, res, V = 256, 32, 4
 pts, cols = point_cloud_for("sphere_shell", N)
@@ -1060,7 +1102,7 @@ pts, cols = pts[:N], cols[:N]
 # hand the tie-break a coin to flip
 pts = pts + 1e-4 * np.random.default_rng(0).standard_normal(pts.shape)
 cams = orbital_rig(V, (0.5, 0.5, 0.5), 1.6, width=res, height=res)
-mesh = jax.make_mesh((2, 2), ("part", "view"))
+mesh = make_mesh((2, 2), ("part", "view"))
 grid = TileGrid(res, res, 8, 16)
 g_gt = from_points(jnp.asarray(pts), jnp.asarray(cols), opacity=0.95)
 gts = jnp.asarray(render_views(g_gt, cams, grid, K=16, bg=0.0)[0])[None]
@@ -1169,6 +1211,7 @@ from repro.core.gaussians import from_points
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, GSOptState
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 
 Pn, N, res, K, V = 2, 256, 32, 16, 2
 grid = TileGrid(res, res, 8, 16)
@@ -1180,8 +1223,8 @@ cam_b = select(cams, jnp.arange(V))
 g_all = from_points(jnp.asarray(pts), jnp.asarray(cols), opacity=0.8)
 part = lambda i: jax.tree.map(lambda x: x[i * N:(i + 1) * N], g_all)
 g_b = jax.tree.map(lambda *xs: jnp.stack(xs), part(0), part(1))
-mesh2d = jax.make_mesh((2, 2), ("part", "view"))
-mesh1d = jax.make_mesh((2,), ("part",))
+mesh2d = make_mesh((2, 2), ("part", "view"))
+mesh1d = make_mesh((2,), ("part",))
 gt = jnp.zeros((V, Pn * T, 3, grid.tile_h, grid.tile_w))
 mask = jnp.ones((V, Pn * T, grid.tile_h, grid.tile_w), bool)
 TR = ("means", "log_scales", "quats", "opacity_logit", "colors")

@@ -246,10 +246,11 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 sys.path.insert(0, "{src}")
+from repro.launch.mesh import make_mesh
 from repro.runtime import CheckpointManager
 
 mode, root = sys.argv[1], sys.argv[2]
-mesh = jax.make_mesh(({d}, 2), ("data", "model"))
+mesh = make_mesh(({d}, 2), ("data", "model"))
 sh = NamedSharding(mesh, P("data", "model"))
 mgr = CheckpointManager(root)
 if mode == "save":

@@ -239,6 +239,7 @@ from repro.core.pipeline import render_views
 from repro.core.tiling import TileGrid
 from repro.core.train import GSTrainCfg, init_opt
 from repro.data.isosurface import point_cloud_for
+from repro.launch.mesh import make_mesh
 from repro.runtime import CheckpointManager
 
 # count schedule probes per driver run: warm start must NOT re-probe init
@@ -253,7 +254,7 @@ N, res, V = 256, 32, 4
 pts, cols = point_cloud_for("sphere_shell", N)
 pts, cols = pts[:N], cols[:N]
 cams = orbital_rig(V, (0.5, 0.5, 0.5), 1.6, width=res, height=res)
-mesh = jax.make_mesh((2, 2), ("part", "view"))
+mesh = make_mesh((2, 2), ("part", "view"))
 grid = TileGrid(res, res, 8, 16)
 
 g_gt = from_points(jnp.asarray(pts), jnp.asarray(cols), opacity=0.95)
