@@ -4,7 +4,9 @@ TPU, through the same entry points a user calls.
     python chip_smoke.py               # one chip
     python chip_smoke.py --four-chips  # the 2x2 ("part", "view") mesh only
 
-One chip: ``repro.launch.train`` trains kingsnake at ``--full`` (4M
+One chip: first the Pallas rasterizer against ``ref``, forward and
+gradient, at the (8, 128) and (8, 16) tiles; then ``repro.launch.train``
+trains kingsnake at ``--full`` (4M
 isosurface points, 512x512 images, (8, 128) tiles, Pallas kernels) as two
 partitions with ghost cells and masks on a 1x1 mesh — 21 steps, one densify
 event, a checkpoint — merges the partitions, then
@@ -23,7 +25,8 @@ one process, which holds the chip; it starts no child process.
 
 ``one_chip`` and ``four_chips`` take a ``Size``: ``TINY`` and ``TINY4``
 rehearse the same phases on CPU at a small size (``impl="interpret"``,
-the CPU tile), which is what the tests drive.
+the CPU tile), which is what the tests drive; they run ``kernels`` with
+``impl="interpret"`` and a few tiles.
 """
 
 from __future__ import annotations
@@ -100,6 +103,13 @@ RENDER_ATOL = 1e-3
 LOSS_RTOL = 1e-4
 PARAM_ATOL = 1e-5
 PARAM_MAX_FRAC = 1e-3
+#: kernel parity, max |d feats| over max |d feats of ref|.  The backward
+#: kernel sums each splat's nine gradients over a tile's pixels in its own
+#: order, XLA's autodiff of ref in another: f32 rounding, ~1e-6 relative.
+#: An index, sign or transmittance bug moves gradients by O(1).
+KERNEL_GRAD_RTOL = 1e-3
+#: tiles the production and CPU configurations rasterize with
+TILES = ((8, 128), (8, 16))
 
 
 def log(msg: str):
@@ -152,6 +162,64 @@ def _device_line():
     d = jax.devices()[0]
     return {"platform": d.platform, "kind": d.device_kind,
             "count": len(jax.devices())}
+
+
+def _kernel_inputs(n_tiles: int, K: int, th: int, tw: int, seed: int = 0):
+    """Random splat feature lists (n_tiles, K, FEAT_DIM) over tiles laid
+    out four across: means in and around the tile, 1-20 px axes, random
+    colors and opacities below 1."""
+    import numpy as np
+
+    from repro.core.tiling import FEAT_DIM
+
+    rng = np.random.default_rng(seed)
+    i = np.arange(n_tiles)
+    o = np.stack([(i % 4) * tw, (i // 4) * th], -1).astype(np.float32)
+    f = np.zeros((n_tiles, K, FEAT_DIM), np.float32)
+    f[..., 0] = o[:, None, 0] + rng.uniform(-20, tw + 20, (n_tiles, K))
+    f[..., 1] = o[:, None, 1] + rng.uniform(-10, th + 10, (n_tiles, K))
+    sa, sc = rng.uniform(1, 20, (2, n_tiles, K))
+    f[..., 2] = 1 / sa ** 2
+    f[..., 3] = rng.uniform(-0.5, 0.5, (n_tiles, K)) / (sa * sc)
+    f[..., 4] = 1 / sc ** 2
+    f[..., 5:8] = rng.uniform(0, 1, (n_tiles, K, 3))
+    f[..., 8] = rng.uniform(0, 0.99, (n_tiles, K))
+    w = rng.standard_normal((n_tiles, 4, th, tw)).astype(np.float32)
+    return f, o, w
+
+
+def kernels(impl: str, n_tiles: int = 512, K: int = 64) -> dict:
+    """The rasterizer's ``impl`` against ``ref``, forward and gradient, at
+    each tile of ``TILES``; raises on failure -> {tile: (fwd, grad)}."""
+    import jax
+    import numpy as np
+
+    from repro.kernels import ops
+
+    report = {}
+    for th, tw in TILES:
+        f, o, w = _kernel_inputs(n_tiles, K, th, tw)
+
+        def run(impl):
+            def loss(x):
+                out = ops.rasterize_tiles(x, o, tile_h=th, tile_w=tw,
+                                          impl=impl)
+                return (out * w).sum(), out
+            (_, out), g = jax.jit(jax.value_and_grad(loss, has_aux=True))(f)
+            return np.asarray(out), np.asarray(g)[..., :9]
+
+        out_k, g_k = run(impl)
+        out_r, g_r = run("ref")
+        fwd = float(np.abs(out_k - out_r).max())
+        grad = float(np.abs(g_k - g_r).max() / np.abs(g_r).max())
+        log(f"kernels {th}x{tw}, {n_tiles} tiles x K={K}: {impl} vs ref "
+            f"forward max|diff| {fwd:.3g} (atol {RENDER_ATOL}), gradient "
+            f"max rel diff {grad:.3g} (rtol {KERNEL_GRAD_RTOL})")
+        if not (fwd <= RENDER_ATOL and grad <= KERNEL_GRAD_RTOL):
+            raise AssertionError(f"{impl} kernels at {th}x{tw} differ from "
+                                 f"ref: forward {fwd}, gradient {grad}")
+        report[(th, tw)] = (fwd, grad)
+    return report
 
 
 def one_chip(size: Size, out: Path = OUT) -> dict:
@@ -340,6 +408,7 @@ def main(argv=None):
     if args.four_chips:
         four_chips(FULL4)
     else:
+        kernels("pallas")
         one_chip(FULL)
     log(f"total {time.perf_counter() - t0:.1f}s (smoke timing)")
     print(json.dumps({"ok": True, "device": _device_line()}), flush=True)

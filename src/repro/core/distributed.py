@@ -67,7 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.cameras import CAM_VAXES, Camera, select
 from repro.core.dtypes import cast_tables
@@ -88,7 +88,6 @@ from repro.core.train import (GSTrainCfg, GSOptState, _check_resume_policy,
 from repro.optim.compress import compress_grads
 from repro.kernels import rasterize_tiles
 from repro.kernels.ops import rasterize_tiles_tiered
-from repro.launch.mesh import check_auto_mesh
 
 NEG = -1e30
 
@@ -130,6 +129,23 @@ def _tile_axes(ax: MeshAxes):
     present subset of (pod, model), replicated when neither exists."""
     present = tuple(a for a in (ax.pod, ax.model) if a)
     return present if present else None
+
+
+def check_auto_mesh(mesh):
+    """Raise a clear ValueError for a mesh with non-Auto axes.
+
+    The trainer is written for Auto axes (shard_map bodies + jit in/out
+    shardings).  Under Explicit axes — ``jax.make_mesh``'s default since
+    jax 0.8 — the vmapped densify's fixed-size ``jnp.nonzero`` lowers to a
+    scatter whose sharding check fails deep inside XLA."""
+    types = getattr(mesh, "axis_types", None) or ()
+    bad = [n for n, t in zip(mesh.axis_names, types) if t != AxisType.Auto]
+    if bad:
+        raise ValueError(
+            f"mesh axes {bad} are not AxisType.Auto (got {types}); the GS "
+            "trainer needs Auto axes — build the mesh with "
+            "repro.launch.mesh.make_mesh (jax.make_mesh defaults to "
+            "Explicit axes since jax 0.8)")
 
 
 def gs_shardings(mesh, *, views: Optional[int] = None):
@@ -1882,6 +1898,26 @@ def rebalance_partitions(g: Gaussians, opt: GSOptState, mesh, *,
     return jax.tree.map(take, gh), jax.tree.map(take, oh), True
 
 
+def _put_unaliased(tree, shardings):
+    """``jax.device_put`` that never returns the caller's own buffers.
+
+    The train step donates its state.  A device_put onto devices an array
+    already lives on may hand back that array's buffers (even with
+    ``may_alias=False`` for a replicated sharding), and the first step would
+    then delete the caller's arrays.  Only leaves that came back aliased are
+    copied, so a scene split over other devices is not duplicated."""
+    def put(x, sh):
+        y = jax.device_put(x, sh)
+        if isinstance(x, jax.Array):
+            held = {s.data.unsafe_buffer_pointer()
+                    for s in x.addressable_shards}
+            if any(s.data.unsafe_buffer_pointer() in held
+                   for s in y.addressable_shards):
+                y = jax.device_put(jnp.copy(x), sh)
+        return y
+    return jax.tree.map(put, tree, shardings)
+
+
 def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
                    *, mesh, steps: int, extent: float, key=None,
                    densify_every: int = 0, densify_from: int = 100,
@@ -2036,11 +2072,7 @@ def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
                 and (i + 1) % densify_every == 0:
             key = jax.random.split(key, 1 + Pn)[0]
 
-    # the step donates its state: copy the caller's arrays first (a
-    # device_put onto a device they already live on may alias them —
-    # even with may_alias=False for a replicated sharding — and the first
-    # step would then delete the caller's gaussians)
-    g_dev = jax.device_put(jax.tree.map(jnp.copy, g), g_sh)
+    g_dev = _put_unaliased(g, g_sh)
     opt_dev = jax.device_put(opt, opt_sh)
     err_dev = jax.device_put(err, err_sh) if compress == "int8" else None
 

@@ -11,7 +11,8 @@ system: the vmapped densify's fixed-size ``jnp.nonzero`` lowers to a scatter
 whose sharding check then fails ("Resource axis: part of
 PartitionSpec('part',) is not found in mesh").  The trainer is written for
 Auto axes (shard_map bodies + jit in/out shardings), so meshes are built
-here and ``check_auto_mesh`` refuses anything else at the trainer's entry.
+here and ``repro.core.distributed.check_auto_mesh`` refuses anything else
+at the trainer's entry.
 """
 
 from __future__ import annotations
@@ -28,18 +29,6 @@ def make_mesh(shape, axes, *, devices=None):
     shape, axes = tuple(shape), tuple(axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
                          devices=devices)
-
-
-def check_auto_mesh(mesh):
-    """Raise a clear ValueError for a mesh with non-Auto axes."""
-    types = getattr(mesh, "axis_types", None) or ()
-    bad = [n for n, t in zip(mesh.axis_names, types) if t != AxisType.Auto]
-    if bad:
-        raise ValueError(
-            f"mesh axes {bad} are not AxisType.Auto (got {types}); the GS "
-            "trainer needs Auto axes — build the mesh with "
-            "repro.launch.mesh.make_mesh (jax.make_mesh defaults to "
-            "Explicit axes since jax 0.8)")
 
 
 def make_production_mesh(*, multi_pod: bool = False):

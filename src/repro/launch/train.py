@@ -104,7 +104,7 @@ def gs_train_cfg(args):
                       tile_h=th, tile_w=tw, impl=impl)
 
 
-def gs_mesh(args, cfg, n_views: int, devices=None):
+def gs_mesh(args, cfg, n_views: int):
     """The ("part", "view") mesh: ``--mesh PxV`` or the widest "view" axis
     the EFFECTIVE minibatch supports (the driver clamps view_batch to the
     view count), the rest going to "part"."""
@@ -112,8 +112,7 @@ def gs_mesh(args, cfg, n_views: int, devices=None):
 
     from repro.launch.mesh import make_mesh
 
-    devices = jax.devices() if devices is None else list(devices)
-    n_dev = len(devices)
+    n_dev = len(jax.devices())
     if args.mesh:
         p, v = (int(x) for x in args.mesh.lower().split("x"))
         if p * v != n_dev:
@@ -122,7 +121,7 @@ def gs_mesh(args, cfg, n_views: int, devices=None):
     else:
         v = math.gcd(max(1, min(cfg.view_batch, n_views)), n_dev)
         p = n_dev // v
-    return make_mesh((p, v), ("part", "view"), devices=devices)
+    return make_mesh((p, v), ("part", "view"))
 
 
 def prepare_gs(args, cfg, ds, n_views: int, part_shards: int):
@@ -567,8 +566,10 @@ def parse_args(argv=None):
                          "Pallas kernels; exits with an error off-TPU")
     ap.add_argument("--impl", default="auto",
                     choices=["auto", "ref", "interpret", "pallas"],
-                    help="rasterizer without --full (auto: pallas on TPU, "
-                         "ref elsewhere)")
+                    help="rasterizer without --full, as serve_gs --impl "
+                         "(auto: pallas on TPU, ref elsewhere); the CPU "
+                         "rehearsal of chip_smoke.py sets 'interpret' to "
+                         "run the kernel bodies without a chip")
     ap.add_argument("--parts", type=int, default=2)
     ap.add_argument("--resolution", type=int, default=None,
                     help="image side (default: the dataset's first "

@@ -42,6 +42,15 @@ def test_one_chip_phases_rehearsal(tmp_path):
     assert (tmp_path / "smoke" / "gs" / "merged").is_dir()
 
 
+def test_kernel_phase_rehearsal():
+    """The kernel parity phase, interpret vs ref at both tiles."""
+    r = chip_smoke.kernels("interpret", n_tiles=8, K=16)
+    assert set(r) == set(chip_smoke.TILES)
+    for fwd, grad in r.values():
+        assert fwd <= chip_smoke.RENDER_ATOL
+        assert 0 <= grad <= chip_smoke.KERNEL_GRAD_RTOL
+
+
 FOUR = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

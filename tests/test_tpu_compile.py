@@ -4,8 +4,10 @@ Interpret-mode parity (test_kernel_rasterize.py and friends) runs the same
 kernel bodies on CPU but cannot see what the chip's Mosaic compiler refuses:
 unaligned block shapes, vector ops with no TPU lowering.  These tests
 compile ``rasterize_fwd``/``rasterize_bwd`` for one chip of a described
-``v5e:2x2`` topology at the production (8, 128) tile, for the tier ladder's
-K values, and assert that the compiled program holds the Pallas kernel.
+``v5e:2x2`` topology, at the production (8, 128) tile and at the CPU
+(8, 16) tile (which runs on a TPU without ``--full``), for the tier
+ladder's K values, and assert that the compiled program holds the Pallas
+kernel.
 
 The topology is described inside a module fixture (never at import), so
 only the worker that runs this file loads the TPU compiler; it skips where
@@ -22,9 +24,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.tiling import FEAT_DIM
 from repro.kernels import rasterize as rk
+from repro.launch.device import CPU_TILE, FULL_TILE
 
-TILE = (8, 128)
 N_TILES = 512
+KS = pytest.mark.parametrize("K", [16, 64, 256])
 
 
 @pytest.fixture(scope="module")
@@ -50,22 +53,38 @@ def _spec(shape, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
 
 
-@pytest.mark.parametrize("K", [16, 64, 256])
-def test_rasterize_fwd_compiles_for_v5e(one_chip, K):
-    th, tw = TILE
+def _compile_fwd(sharding, K, tile):
+    th, tw = tile
     fwd = jax.jit(lambda f, o: rk.rasterize_fwd(f, o, tile_h=th, tile_w=tw))
-    compiled = fwd.lower(_spec((N_TILES, K, FEAT_DIM), one_chip),
-                         _spec((N_TILES, 2), one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    return fwd.lower(_spec((N_TILES, K, FEAT_DIM), sharding),
+                     _spec((N_TILES, 2), sharding)).compile().as_text()
 
 
-@pytest.mark.parametrize("K", [16, 64, 256])
-def test_rasterize_bwd_compiles_for_v5e(one_chip, K):
-    th, tw = TILE
+def _compile_bwd(sharding, K, tile):
+    th, tw = tile
     bwd = jax.jit(lambda f, o, out, g: rk.rasterize_bwd(
         f, o, out, g, tile_h=th, tile_w=tw))
-    planes = _spec((N_TILES, 4, th, tw), one_chip)
-    compiled = bwd.lower(_spec((N_TILES, K, FEAT_DIM), one_chip),
-                         _spec((N_TILES, 2), one_chip),
-                         planes, planes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    planes = _spec((N_TILES, 4, th, tw), sharding)
+    return bwd.lower(_spec((N_TILES, K, FEAT_DIM), sharding),
+                     _spec((N_TILES, 2), sharding),
+                     planes, planes).compile().as_text()
+
+
+@KS
+def test_rasterize_fwd_compiles_for_v5e(one_chip, K):
+    assert "tpu_custom_call" in _compile_fwd(one_chip, K, FULL_TILE)
+
+
+@KS
+def test_rasterize_bwd_compiles_for_v5e(one_chip, K):
+    assert "tpu_custom_call" in _compile_bwd(one_chip, K, FULL_TILE)
+
+
+@KS
+def test_rasterize_fwd_compiles_for_v5e_cpu_tile(one_chip, K):
+    assert "tpu_custom_call" in _compile_fwd(one_chip, K, CPU_TILE)
+
+
+@KS
+def test_rasterize_bwd_compiles_for_v5e_cpu_tile(one_chip, K):
+    assert "tpu_custom_call" in _compile_bwd(one_chip, K, CPU_TILE)
