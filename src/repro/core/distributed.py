@@ -83,6 +83,7 @@ from repro.core.tiling import (DEFAULT_ASSIGN_IMPL, DEFAULT_TILE_BUDGET,
                                tile_occupancy, tile_tiers,
                                topk_by_score_then_index,
                                window_overlap_mask)
+from repro.core.trace import scope, span, step_span
 from repro.core.train import (GSTrainCfg, GSOptState, _check_resume_policy,
                               densify_and_prune, group_lrs, init_opt)
 from repro.optim.compress import compress_grads
@@ -217,6 +218,7 @@ def _all_gather_rows(x, axis_name, nax: int):
 # ---------------------------------------------------------------------------
 
 
+@scope("assign")
 def _assign_tiles_local(mean2d, radius, depth, valid, lo, hi, *, K: int,
                         block: int, impl: str = "dense",
                         grid: Optional[TileGrid] = None, t0=None,
@@ -297,6 +299,7 @@ def _assign_tiles_local(mean2d, radius, depth, valid, lo, hi, *, K: int,
     return idx, score, jnp.zeros((), jnp.int32)
 
 
+@scope("loss")
 def _loss_partials(pred, gt, mask, *, win_size: int = 7):
     """Local partial sums for masked L1 + per-tile D-SSIM.
 
@@ -570,183 +573,185 @@ def make_gs_forward(mesh, grid: TileGrid, *, K: int, impl: str = "auto",
         # ---- stage 1 (gaussian-parallel over "part"): project locally.
         # With a "view" mesh axis, cam/gt/mask arrive already view-sharded:
         # this body only ever sees its Vl = V/n_view local views.
-        if views:
-            # (Vl, Pl, Nl, ...): per-view projection of the same local shard
-            splats = jax.vmap(lambda c: project(g, c),
-                              in_axes=(CAM_VAXES,))(cam)
-        else:
-            splats = project(g, cam)                # (Pl, Nl, ...)
+        with scope("project"):
+            if views:
+                # (Vl, Pl, Nl, ...): per-view projection of the same local shard
+                splats = jax.vmap(lambda c: project(g, c),
+                                  in_axes=(CAM_VAXES,))(cam)
+            else:
+                splats = project(g, cam)                # (Pl, Nl, ...)
 
-        # ---- local compact tables: the per-splat rows both handoffs move
-        if gather_mode == "split":
-            radius_v = jnp.where(splats.valid, splats.radius, 0.0)
-            geo_l = jnp.stack(
-                [splats.mean2d[..., 0], splats.mean2d[..., 1],
-                 radius_v, splats.depth], axis=-1)             # (Pl,Nl,4) f32
-            a, b, c = (splats.cov2d[..., 0], splats.cov2d[..., 1],
-                       splats.cov2d[..., 2])
-            det = jnp.maximum(a * c - b * b, 1e-12)
-            alpha_v = jnp.where(splats.valid, splats.alpha, 0.0)
-            rest_l = jnp.stack(
-                [c / det, -b / det, a / det,
-                 splats.rgb[..., 0], splats.rgb[..., 1], splats.rgb[..., 2],
-                 alpha_v, jnp.zeros_like(alpha_v)],
-                axis=-1).astype(jnp.bfloat16)                  # (Pl,Nl,8)
-            tabs_l = (geo_l, rest_l)
-        else:
-            feat_l = splat_features(splats)                    # (Pl,Nl,F)
-            aux_l = jnp.stack(
-                [splats.radius, splats.depth,
-                 splats.valid.astype(jnp.float32)], axis=-1)   # (Pl,Nl,3)
-            tabs_l = (feat_l, aux_l)
+            # ---- local compact tables: the per-splat rows both handoffs move
+            if gather_mode == "split":
+                radius_v = jnp.where(splats.valid, splats.radius, 0.0)
+                geo_l = jnp.stack(
+                    [splats.mean2d[..., 0], splats.mean2d[..., 1],
+                     radius_v, splats.depth], axis=-1)             # (Pl,Nl,4) f32
+                a, b, c = (splats.cov2d[..., 0], splats.cov2d[..., 1],
+                           splats.cov2d[..., 2])
+                det = jnp.maximum(a * c - b * b, 1e-12)
+                alpha_v = jnp.where(splats.valid, splats.alpha, 0.0)
+                rest_l = jnp.stack(
+                    [c / det, -b / det, a / det,
+                     splats.rgb[..., 0], splats.rgb[..., 1], splats.rgb[..., 2],
+                     alpha_v, jnp.zeros_like(alpha_v)],
+                    axis=-1).astype(jnp.bfloat16)                  # (Pl,Nl,8)
+                tabs_l = (geo_l, rest_l)
+            else:
+                feat_l = splat_features(splats)                    # (Pl,Nl,F)
+                aux_l = jnp.stack(
+                    [splats.radius, splats.depth,
+                     splats.valid.astype(jnp.float32)], axis=-1)   # (Pl,Nl,3)
+                tabs_l = (feat_l, aux_l)
 
-        # mixed-precision boundary: drop the wire tables to the policy's
-        # storage dtype BEFORE the collective (identity under "f32") —
-        # payload halves here, and the backward psum-scatter of the
-        # all-gather reduces in the same dtype (honest 2x both directions)
-        tabs_l = cast_tables(tabs_l, dtype_policy)
+            # mixed-precision boundary: drop the wire tables to the policy's
+            # storage dtype BEFORE the collective (identity under "f32") —
+            # payload halves here, and the backward psum-scatter of the
+            # all-gather reduces in the same dtype (honest 2x both directions)
+            tabs_l = cast_tables(tabs_l, dtype_policy)
 
         fold = lambda x: x.reshape((-1,) + x.shape[2:])
         t0_strip = lax.axis_index(model) * Tl if model is not None else None
 
-        if exchange:
-            # ---- sparse-overlap exchange: pack only the splats whose
-            # bboxes overlap each destination's sub-window (module
-            # docstring).  A scalar budget moves one uniform all_to_all;
-            # a per-edge budget matrix moves a ragged ppermute ladder.
-            if views:
-                tabs_l = tuple(fold(x) for x in tabs_l)        # (R, Nl, C)
-            Nl = tabs_l[0].shape[1]
-            # overlap geometry in f32 (promote is a no-op under "f32"):
-            # the send-side bbox test must run the same arithmetic as the
-            # receive-side assignment on the same rounded values
-            mx_l = tabs_l[0][..., 0].astype(jnp.float32)
-            my_l = tabs_l[0][..., 1].astype(jnp.float32)
-            if gather_mode == "split":
-                rad_l = tabs_l[0][..., 2].astype(jnp.float32)
-                val_l = rad_l > 0                  # geo radius, valid-masked
-            else:
-                rad_l = tabs_l[1][..., 0].astype(jnp.float32)  # aux (raw)
-                val_l = tabs_l[1][..., 2] > 0.5
-            base = 0 if t0_strip is None else t0_strip
-            t0_all = base + jnp.arange(n_data, dtype=jnp.int32) * sub
-            # t_end clips padded sub-windows at the strip's real tiles:
-            # pad slots pack (and count) nothing, partial windows never
-            # charge the next strip's rows against an edge budget
-            hit = window_overlap_mask(mx_l, my_l, rad_l, val_l, grid,
-                                      t0=t0_all, n_local=sub,
-                                      t_end=(base + Tl) if pad else None)
-            # hit (n_data, R, Nl): slab d = MY splats destined for the
-            # device at part-index d.  Candidates past the edge budget are
-            # counted, never silently dropped.
-            counts = hit.sum(-1, dtype=jnp.int32)
-            if ex_budget_mat is None:
-                E = min(int(exchange_budget), Nl) if exchange_budget \
-                    else Nl
-                exchange_ov_l = jnp.maximum(counts - E, 0).sum() \
-                    .astype(jnp.int32)
-                slots = jax.vmap(jax.vmap(
-                    lambda m: jnp.nonzero(m, size=E, fill_value=Nl)[0]))(hit)
+        with scope("transport"):
+            if exchange:
+                # ---- sparse-overlap exchange: pack only the splats whose
+                # bboxes overlap each destination's sub-window (module
+                # docstring).  A scalar budget moves one uniform all_to_all;
+                # a per-edge budget matrix moves a ragged ppermute ladder.
+                if views:
+                    tabs_l = tuple(fold(x) for x in tabs_l)        # (R, Nl, C)
+                Nl = tabs_l[0].shape[1]
+                # overlap geometry in f32 (promote is a no-op under "f32"):
+                # the send-side bbox test must run the same arithmetic as the
+                # receive-side assignment on the same rounded values
+                mx_l = tabs_l[0][..., 0].astype(jnp.float32)
+                my_l = tabs_l[0][..., 1].astype(jnp.float32)
+                if gather_mode == "split":
+                    rad_l = tabs_l[0][..., 2].astype(jnp.float32)
+                    val_l = rad_l > 0                  # geo radius, valid-masked
+                else:
+                    rad_l = tabs_l[1][..., 0].astype(jnp.float32)  # aux (raw)
+                    val_l = tabs_l[1][..., 2] > 0.5
+                base = 0 if t0_strip is None else t0_strip
+                t0_all = base + jnp.arange(n_data, dtype=jnp.int32) * sub
+                # t_end clips padded sub-windows at the strip's real tiles:
+                # pad slots pack (and count) nothing, partial windows never
+                # charge the next strip's rows against an edge budget
+                hit = window_overlap_mask(mx_l, my_l, rad_l, val_l, grid,
+                                          t0=t0_all, n_local=sub,
+                                          t_end=(base + Tl) if pad else None)
+                # hit (n_data, R, Nl): slab d = MY splats destined for the
+                # device at part-index d.  Candidates past the edge budget are
+                # counted, never silently dropped.
+                counts = hit.sum(-1, dtype=jnp.int32)
+                if ex_budget_mat is None:
+                    E = min(int(exchange_budget), Nl) if exchange_budget \
+                        else Nl
+                    exchange_ov_l = jnp.maximum(counts - E, 0).sum() \
+                        .astype(jnp.int32)
+                    slots = jax.vmap(jax.vmap(
+                        lambda m: jnp.nonzero(m, size=E, fill_value=Nl)[0]))(hit)
 
-                def exch(x):
-                    sent = jax.vmap(lambda s: jax.vmap(
-                        lambda row, i: jnp.take(row, i, axis=0, mode="fill",
-                                                fill_value=0))(x, s))(slots)
-                    got = lax.all_to_all(sent, data, 0, 0, tiled=True)
-                    # got's axis 0 is the SOURCE part index: flattening it
-                    # src-major keeps ascending local rows inside each
-                    # source — an order-preserving subsequence of the
-                    # all-gather table, so the two-key (score, index) top-k
-                    # selects the identical splats whenever E covers.  Fill
-                    # slots carry radius 0 / valid 0: dead to assignment
-                    # and compositing.
-                    return got.transpose(1, 0, 2, 3).reshape(
-                        (got.shape[1], n_data * E) + got.shape[3:])
-            else:
-                # ---- ragged per-edge transport: all_to_all needs uniform
-                # chunks, so the (n, n) budget matrix rides a ppermute
-                # LADDER — ring shift k carries every (s -> (s+k) % n) edge
-                # at once in a slab sized by the worst edge on that shift;
-                # each source masks its slab past its own B[src, dst], so
-                # the per-edge cap is exact and the wire payload is
-                # sum_k E_shift[k] rows, not n * max(B).
-                Bm = np.minimum(ex_budget_mat, Nl).astype(np.int32)
-                ring = (np.arange(n_data) + np.arange(n_data)[:, None]) \
-                    % n_data                       # ring[k, s] = (s+k) % n
-                # overlap-aware window assignment: device i renders band
-                # tau[i], chosen so each brick's dominant band rides the
-                # free local shift (window_assignment docstring).  The
-                # (P, T) tile layout of return_tiles is band-ordered, so
-                # that path keeps the identity assignment.
-                tau_np = np.arange(n_data, dtype=np.int64) if return_tiles \
-                    else window_assignment(Bm)
-                tau_arr = jnp.asarray(tau_np, jnp.int32)
-                band = tau_np[ring]        # band[k, s]: dst band, shift k
-                E_shift = tuple(
-                    int(Bm[np.arange(n_data), band[k]].max())
-                    for k in range(n_data))
-                R_tot = int(sum(E_shift))
-                me = lax.axis_index(data)
-                b_row = jnp.take(jnp.asarray(Bm), me, axis=0)      # (n,)
-                exchange_ov_edges = jnp.maximum(
-                    counts - b_row[:, None], 0).sum(1).astype(jnp.int32)
-                exchange_ov_l = exchange_ov_edges.sum()
-                exchange_demand_l = counts.max(1).astype(jnp.int32)
-                slot_by_shift = []
-                for k in range(n_data):
-                    # rows for the BAND the shift-k destination renders
-                    hk = jnp.take(hit, jnp.take(tau_arr, (me + k) % n_data),
-                                  axis=0)                          # (R, Nl)
-                    sl = jax.vmap(
-                        lambda m, _E=E_shift[k]: jnp.nonzero(
-                            m, size=_E, fill_value=Nl)[0])(hk)
-                    # my own edge budget on this shift, B[me, tau[(me+k)
-                    # % n]]: slots past it become fill rows (counted above)
-                    cap = jnp.take(
-                        jnp.asarray(Bm[np.arange(n_data), band[k]]), me)
-                    slot_by_shift.append(
-                        jnp.where(jnp.arange(E_shift[k]) < cap, sl, Nl))
-                # receive side: shift k delivers src (me - k) % n; packing
-                # the slabs back in SRC order (exclusive cumsum of the
-                # static per-shift sizes, permuted to src order) keeps the
-                # table an order-preserving subsequence of the all-gather
-                # table — same two-key top-k parity as the uniform path
-                src_shift = (me - jnp.arange(n_data)) % n_data
-                sizes_by_src = jnp.take(
-                    jnp.asarray(E_shift, jnp.int32), src_shift)
-                offs = jnp.concatenate(
-                    [jnp.zeros((1,), jnp.int32),
-                     jnp.cumsum(sizes_by_src)[:-1].astype(jnp.int32)])
-
-                def exch(x):
-                    out = jnp.zeros((x.shape[0], R_tot) + x.shape[2:],
-                                    x.dtype)
+                    def exch(x):
+                        sent = jax.vmap(lambda s: jax.vmap(
+                            lambda row, i: jnp.take(row, i, axis=0, mode="fill",
+                                                    fill_value=0))(x, s))(slots)
+                        got = lax.all_to_all(sent, data, 0, 0, tiled=True)
+                        # got's axis 0 is the SOURCE part index: flattening it
+                        # src-major keeps ascending local rows inside each
+                        # source — an order-preserving subsequence of the
+                        # all-gather table, so the two-key (score, index) top-k
+                        # selects the identical splats whenever E covers.  Fill
+                        # slots carry radius 0 / valid 0: dead to assignment
+                        # and compositing.
+                        return got.transpose(1, 0, 2, 3).reshape(
+                            (got.shape[1], n_data * E) + got.shape[3:])
+                else:
+                    # ---- ragged per-edge transport: all_to_all needs uniform
+                    # chunks, so the (n, n) budget matrix rides a ppermute
+                    # LADDER — ring shift k carries every (s -> (s+k) % n) edge
+                    # at once in a slab sized by the worst edge on that shift;
+                    # each source masks its slab past its own B[src, dst], so
+                    # the per-edge cap is exact and the wire payload is
+                    # sum_k E_shift[k] rows, not n * max(B).
+                    Bm = np.minimum(ex_budget_mat, Nl).astype(np.int32)
+                    ring = (np.arange(n_data) + np.arange(n_data)[:, None]) \
+                        % n_data                       # ring[k, s] = (s+k) % n
+                    # overlap-aware window assignment: device i renders band
+                    # tau[i], chosen so each brick's dominant band rides the
+                    # free local shift (window_assignment docstring).  The
+                    # (P, T) tile layout of return_tiles is band-ordered, so
+                    # that path keeps the identity assignment.
+                    tau_np = np.arange(n_data, dtype=np.int64) if return_tiles \
+                        else window_assignment(Bm)
+                    tau_arr = jnp.asarray(tau_np, jnp.int32)
+                    band = tau_np[ring]        # band[k, s]: dst band, shift k
+                    E_shift = tuple(
+                        int(Bm[np.arange(n_data), band[k]].max())
+                        for k in range(n_data))
+                    R_tot = int(sum(E_shift))
+                    me = lax.axis_index(data)
+                    b_row = jnp.take(jnp.asarray(Bm), me, axis=0)      # (n,)
+                    exchange_ov_edges = jnp.maximum(
+                        counts - b_row[:, None], 0).sum(1).astype(jnp.int32)
+                    exchange_ov_l = exchange_ov_edges.sum()
+                    exchange_demand_l = counts.max(1).astype(jnp.int32)
+                    slot_by_shift = []
                     for k in range(n_data):
-                        sent = jax.vmap(
-                            lambda row, i: jnp.take(
-                                row, i, axis=0, mode="fill",
-                                fill_value=0))(x, slot_by_shift[k])
-                        got = sent if k == 0 else lax.ppermute(
-                            sent, data,
-                            perm=[(s, (s + k) % n_data)
-                                  for s in range(n_data)])
-                        off = jnp.take(offs, (me - k) % n_data)
-                        out = lax.dynamic_update_slice_in_dim(
-                            out, got, off, axis=1)
-                    return out
+                        # rows for the BAND the shift-k destination renders
+                        hk = jnp.take(hit, jnp.take(tau_arr, (me + k) % n_data),
+                                      axis=0)                          # (R, Nl)
+                        sl = jax.vmap(
+                            lambda m, _E=E_shift[k]: jnp.nonzero(
+                                m, size=_E, fill_value=Nl)[0])(hk)
+                        # my own edge budget on this shift, B[me, tau[(me+k)
+                        # % n]]: slots past it become fill rows (counted above)
+                        cap = jnp.take(
+                            jnp.asarray(Bm[np.arange(n_data), band[k]]), me)
+                        slot_by_shift.append(
+                            jnp.where(jnp.arange(E_shift[k]) < cap, sl, Nl))
+                    # receive side: shift k delivers src (me - k) % n; packing
+                    # the slabs back in SRC order (exclusive cumsum of the
+                    # static per-shift sizes, permuted to src order) keeps the
+                    # table an order-preserving subsequence of the all-gather
+                    # table — same two-key top-k parity as the uniform path
+                    src_shift = (me - jnp.arange(n_data)) % n_data
+                    sizes_by_src = jnp.take(
+                        jnp.asarray(E_shift, jnp.int32), src_shift)
+                    offs = jnp.concatenate(
+                        [jnp.zeros((1,), jnp.int32),
+                         jnp.cumsum(sizes_by_src)[:-1].astype(jnp.int32)])
 
-            tabs = tuple(exch(x) for x in tabs_l)
-        else:
-            # ---- Grendel handoff: all-gather the SMALL projected table
-            # over "part".  bwd(all_gather) = psum_scatter -> grads return
-            # sharded.
-            tabs = tuple(_all_gather_rows(x, data, nax) for x in tabs_l)
-            if views:
-                # fold the LOCAL view axis into the partition axis:
-                # (Vl, Pl, ...) -> (Vl*Pl, ...) — stage 2 and the kernel
-                # launch are view-count agnostic
-                tabs = tuple(fold(x) for x in tabs)
-            exchange_ov_l = jnp.zeros((), jnp.int32)
+                    def exch(x):
+                        out = jnp.zeros((x.shape[0], R_tot) + x.shape[2:],
+                                        x.dtype)
+                        for k in range(n_data):
+                            sent = jax.vmap(
+                                lambda row, i: jnp.take(
+                                    row, i, axis=0, mode="fill",
+                                    fill_value=0))(x, slot_by_shift[k])
+                            got = sent if k == 0 else lax.ppermute(
+                                sent, data,
+                                perm=[(s, (s + k) % n_data)
+                                      for s in range(n_data)])
+                            off = jnp.take(offs, (me - k) % n_data)
+                            out = lax.dynamic_update_slice_in_dim(
+                                out, got, off, axis=1)
+                        return out
+
+                tabs = tuple(exch(x) for x in tabs_l)
+            else:
+                # ---- Grendel handoff: all-gather the SMALL projected table
+                # over "part".  bwd(all_gather) = psum_scatter -> grads return
+                # sharded.
+                tabs = tuple(_all_gather_rows(x, data, nax) for x in tabs_l)
+                if views:
+                    # fold the LOCAL view axis into the partition axis:
+                    # (Vl, Pl, ...) -> (Vl*Pl, ...) — stage 2 and the kernel
+                    # launch are view-count agnostic
+                    tabs = tuple(fold(x) for x in tabs)
+                exchange_ov_l = jnp.zeros((), jnp.int32)
 
         # assignment geometry promotes to f32 (no-op under "f32"): scoring
         # and depth ordering run f32 arithmetic on the policy-rounded
@@ -843,6 +848,7 @@ def make_gs_forward(mesh, grid: TileGrid, *, K: int, impl: str = "auto",
         idx = lax.stop_gradient(idx)
         live = lax.stop_gradient(score) > NEG / 2   # (Pl, Tl, K)
 
+        @scope("gather")
         def features_for(p_rows, idx_rows, live_rows):
             """Kernel features for arbitrary tile rows: p_rows (...,) picks
             the partition slice of the gathered table, idx_rows (..., K')
@@ -863,65 +869,67 @@ def make_gs_forward(mesh, grid: TileGrid, *, K: int, impl: str = "auto",
             return jnp.concatenate(
                 [feat_t[..., :8], alpha[..., None], feat_t[..., 9:]], -1)
 
-        Pl = mean_g.shape[0]
-        origins = jnp.tile(lo, (Pl, 1))                 # (Pl*Tl, 2)
-        if k_tiers is not None:
-            # ---- tiered dispatch over the window's flat tile axis ----
-            M = Pl * Wl
-            idx_f = idx.reshape(M, K)
-            live_f = live.reshape(M, K)
-            occ = live_f.sum(-1).astype(jnp.int32)
-            caps = tier_caps if tier_caps is not None \
-                else (M,) * len(k_tiers)
-            plan = bin_tiles_by_occupancy(occ, k_tiers, caps)
-            overflow_l = plan.overflow
-            tier_feats, tier_origins = [], []
-            for k, ids in zip(k_tiers, plan.tile_ids):
-                safe = jnp.minimum(ids, M - 1)          # sentinel-safe rows
-                live_rows = live_f[safe, :k] & (ids < M)[:, None]
-                tier_feats.append(
-                    features_for(safe // Wl, idx_f[safe, :k], live_rows))
-                tier_origins.append(jnp.take(origins, ids, axis=0,
-                                             mode="fill", fill_value=0.0))
-            tiles = rasterize_tiles_tiered(
-                tier_feats, tier_origins, plan.tile_ids, M,
-                tile_h=grid.tile_h, tile_w=grid.tile_w, impl=impl)
-        else:
-            p_rows = jnp.broadcast_to(
-                jnp.arange(Pl, dtype=jnp.int32)[:, None], idx.shape[:2])
-            tile_feat = features_for(p_rows, idx, live)  # (Pl,Wl,K,F)
-            flat = tile_feat.reshape(Pl * Wl, K, FEAT_DIM)
-            tiles = rasterize_tiles(flat, origins, tile_h=grid.tile_h,
-                                    tile_w=grid.tile_w, impl=impl)
-            overflow_l = jnp.zeros((), jnp.int32)   # dense path never drops
+        with scope("raster"):
+            Pl = mean_g.shape[0]
+            origins = jnp.tile(lo, (Pl, 1))                 # (Pl*Tl, 2)
+            if k_tiers is not None:
+                # ---- tiered dispatch over the window's flat tile axis ----
+                M = Pl * Wl
+                idx_f = idx.reshape(M, K)
+                live_f = live.reshape(M, K)
+                occ = live_f.sum(-1).astype(jnp.int32)
+                caps = tier_caps if tier_caps is not None \
+                    else (M,) * len(k_tiers)
+                plan = bin_tiles_by_occupancy(occ, k_tiers, caps)
+                overflow_l = plan.overflow
+                tier_feats, tier_origins = [], []
+                for k, ids in zip(k_tiers, plan.tile_ids):
+                    safe = jnp.minimum(ids, M - 1)          # sentinel-safe rows
+                    live_rows = live_f[safe, :k] & (ids < M)[:, None]
+                    tier_feats.append(
+                        features_for(safe // Wl, idx_f[safe, :k], live_rows))
+                    tier_origins.append(jnp.take(origins, ids, axis=0,
+                                                 mode="fill", fill_value=0.0))
+                tiles = rasterize_tiles_tiered(
+                    tier_feats, tier_origins, plan.tile_ids, M,
+                    tile_h=grid.tile_h, tile_w=grid.tile_w, impl=impl)
+            else:
+                p_rows = jnp.broadcast_to(
+                    jnp.arange(Pl, dtype=jnp.int32)[:, None], idx.shape[:2])
+                tile_feat = features_for(p_rows, idx, live)  # (Pl,Wl,K,F)
+                flat = tile_feat.reshape(Pl * Wl, K, FEAT_DIM)
+                tiles = rasterize_tiles(flat, origins, tile_h=grid.tile_h,
+                                        tile_w=grid.tile_w, impl=impl)
+                overflow_l = jnp.zeros((), jnp.int32)   # dense path never drops
 
         # ---- masked loss partials -> psum (scalar-only cross-pod traffic).
         # The partial psum runs over the present (pod, part, model) axes —
         # it must NOT cross "view" shards, whose partials belong to
         # different views; the view axis contributes one scalar pmean at
         # the very end instead.
-        axes = tuple(a for a in (pod, data, model) if a)
-        if views:
-            # per-view partials ((Vl,) vectors through the psum), then the
-            # mean of per-view losses — the same equal-view weighting as
-            # train.py's minibatch step, regardless of how many masked
-            # pixels each view has.  mean over local views + pmean over the
-            # "view" axis == the global V-view mean (equal local counts).
-            pred_v = tiles[:, :3].reshape((vloc, -1, 3) + tiles.shape[2:])
-            l1n, l1d, sn, sd = jax.vmap(
-                partial(_loss_partials, win_size=win_size))(pred_v, gt, mask)
-            l1n, l1d, sn, sd = (lax.psum(x, axes) for x in (l1n, l1d, sn, sd))
-            loss = ((1 - lambda_dssim) * l1n / jnp.maximum(l1d, 1.0)
-                    + lambda_dssim
-                    * (1.0 - sn / jnp.maximum(sd, 1.0)) / 2.0).mean()
-            if view is not None:
-                loss = lax.pmean(loss, view)
-        else:
-            l1n, l1d, sn, sd = _loss_partials(tiles[:, :3], gt, mask,
-                                              win_size=win_size)
-            l1n, l1d, sn, sd = (lax.psum(x, axes) for x in (l1n, l1d, sn, sd))
-            loss = ((1 - lambda_dssim) * l1n / jnp.maximum(l1d, 1.0)
-                    + lambda_dssim * (1.0 - sn / jnp.maximum(sd, 1.0)) / 2.0)
+        with scope("loss"):
+            axes = tuple(a for a in (pod, data, model) if a)
+            if views:
+                # per-view partials ((Vl,) vectors through the psum), then the
+                # mean of per-view losses — the same equal-view weighting as
+                # train.py's minibatch step, regardless of how many masked
+                # pixels each view has.  mean over local views + pmean over the
+                # "view" axis == the global V-view mean (equal local counts).
+                pred_v = tiles[:, :3].reshape((vloc, -1, 3) + tiles.shape[2:])
+                l1n, l1d, sn, sd = jax.vmap(
+                    partial(_loss_partials, win_size=win_size))(pred_v, gt, mask)
+                l1n, l1d, sn, sd = (lax.psum(x, axes) for x in (l1n, l1d, sn, sd))
+                loss = ((1 - lambda_dssim) * l1n / jnp.maximum(l1d, 1.0)
+                        + lambda_dssim
+                        * (1.0 - sn / jnp.maximum(sd, 1.0)) / 2.0).mean()
+                if view is not None:
+                    loss = lax.pmean(loss, view)
+            else:
+                l1n, l1d, sn, sd = _loss_partials(tiles[:, :3], gt, mask,
+                                                  win_size=win_size)
+                l1n, l1d, sn, sd = (lax.psum(x, axes) for x in (l1n, l1d, sn, sd))
+                loss = ((1 - lambda_dssim) * l1n / jnp.maximum(l1d, 1.0)
+                        + lambda_dssim * (1.0 - sn / jnp.maximum(sd, 1.0)) / 2.0)
         if return_tiles or return_overflow:
             outs = (loss,)
             if return_tiles:
@@ -1670,6 +1678,7 @@ def make_gs_train_step(mesh, cfg: GSTrainCfg, grid: TileGrid, extent: float,
 
     compress = cfg.grad_compress
 
+    @scope("adam")
     def adam(g: Gaussians, opt: GSOptState, grads, loss, overflow):
         s = opt.step + 1
         bc1 = 1.0 - cfg.b1 ** s.astype(jnp.float32)
@@ -1988,6 +1997,13 @@ def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
     residual is always re-zeroed at the boundary.  A restorable on-disk
     checkpoint takes precedence.  ``densify_cap=`` bounds the LIVE splat
     count per partition during densify (see ``GSTrainCfg.densify_cap``).
+
+    Tracing (``core.trace``): each iteration is the profiler step
+    ``gs.fit.step`` and holds the host spans ``gs.fit.put`` (minibatch
+    put), ``gs.fit.build`` (a new step program; its first dispatch
+    compiles), ``gs.fit.dispatch``, ``gs.fit.sync`` (the loss read),
+    ``gs.fit.schedule`` (overflow counters), ``gs.fit.densify`` (densify,
+    re-probes, rebalance) and ``gs.fit.ckpt``.
     """
     check_auto_mesh(mesh)
     if grid is None:
@@ -2148,14 +2164,19 @@ def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
                 assign["impl"], assign["budget"],
                 cfg.exchange, ex.budget_key() if ex else None)
         if spec not in step_cache:
-            step_cache[spec] = make_gs_train_step(
-                mesh, cfg, grid, extent, impl=impl, views=vb,
-                k_tiers=sched.k_tiers if sched else None,
-                tier_caps=sched.tier_caps if sched else None,
-                return_overflow=True, win_size=win_size,
-                assign_impl=assign["impl"], assign_budget=assign["budget"],
-                exchange=cfg.exchange,
-                exchange_budget=ex.budget if ex else None)
+            # the recompile marker: the next dispatch compiles this program
+            caps = {f"cap_k{k}": c for k, c in zip(
+                sched.k_tiers, sched.tier_caps or ())} if sched else {}
+            with span("fit.build", **caps):
+                step_cache[spec] = make_gs_train_step(
+                    mesh, cfg, grid, extent, impl=impl, views=vb,
+                    k_tiers=sched.k_tiers if sched else None,
+                    tier_caps=sched.tier_caps if sched else None,
+                    return_overflow=True, win_size=win_size,
+                    assign_impl=assign["impl"],
+                    assign_budget=assign["budget"],
+                    exchange=cfg.exchange,
+                    exchange_budget=ex.budget if ex else None)
         return step_cache[spec]
 
     def save(step_no):
@@ -2180,81 +2201,98 @@ def fit_partitions(g: Gaussians, cams: Camera, gts, masks, cfg: GSTrainCfg,
                              g_dev.trainable()), err_sh)
 
     for i in range(start, steps):
-        vi = (i * vb + np.arange(vb)) % V
-        batch = {
-            "gt_tiles": jax.device_put(jnp.asarray(gt_tiles[vi]),
-                                       b_sh["gt_tiles"]),
-            "mask_tiles": jax.device_put(jnp.asarray(mask_tiles[vi]),
-                                         b_sh["mask_tiles"]),
-            "cam": jax.device_put(select(cams, jnp.asarray(vi)),
-                                  b_sh["cam"]),
-        }
-        if compress == "none":
-            out = get_step()(g_dev, opt_dev, batch)
-            g_dev, opt_dev, loss = out[:3]
-            ov = out[3]
-        else:
-            out = get_step()(g_dev, opt_dev, err_dev, batch)
-            g_dev, opt_dev, err_dev, loss = out[:4]
-            ov = out[4]
-        losses.append(float(loss))
-        if sched is not None:
-            # a non-zero (psum'd) counter grows the caps for the NEXT
-            # steps — a one-step blip, never a persistent silent truncation
-            sched.note_overflow(ov["tiles"], m_dev)
-        if assign["impl"] == "sorted" \
-                and int(np.asarray(ov["assign"]).sum()) > 0:
-            # radii drifted past the sorted budget's probe slack between
-            # densify events: grow it geometrically (same honesty contract)
-            assign["budget"] = grow_tile_budget(
-                assign["budget"] or DEFAULT_TILE_BUDGET, grid.n_tiles)
-        if ex is not None:
-            # matrix budgets grow only the starved edges (per-edge psum'd
-            # counter); scalar budgets keep the total-count contract
-            ex.note_overflow(ov.get("exchange_edges", ov["exchange"]), Nl)
-            if "exchange_demand" in ov:
-                dm = np.asarray(ov["exchange_demand"])
-                ex_demand = dm if ex_demand is None \
-                    else np.maximum(ex_demand, dm)
-        if densify_every and i >= densify_from \
-                and (i + 1) % densify_every == 0:
-            ks = jax.random.split(key, 1 + Pn)
-            key = ks[0]
-            g_dev, opt_dev = densify(g_dev, opt_dev, ks[1:])
-            # the vmapped densify jit picks its own output shardings; pin
-            # the state back onto the step's (pod, part) layout before the
-            # next donating pjit call
-            g_dev = jax.device_put(g_dev, g_sh)
-            opt_dev = jax.device_put(opt_dev, opt_sh)
-            reset_err()  # row count changed: residuals no longer aligned
-            probe_assign(g_dev)  # splat sizes shifted: re-size the budget
-            if sched is not None:
-                reprobe(g_dev)  # occupancy shifted: re-pick tiers/caps
-            if ex is not None and not ex_pinned and ex_demand is not None:
-                # in-step resize, no host probe round-trip: densify clones
-                # at most cfg.max_new rows per partition, so the running
-                # per-edge demand + max_new upper-bounds the post-densify
-                # overlap on every edge
-                ex.ensure(ex_demand + cfg.max_new, Nl)
-            else:
-                reprobe_exchange(g_dev)  # overlap pattern shifted too
-        if rebalance_every and (i + 1) % rebalance_every == 0:
-            g_reb, opt_reb, moved = rebalance_partitions(
-                g_dev, opt_dev, mesh, threshold=rebalance_threshold)
-            if moved:
-                g_dev = jax.device_put(g_reb, g_sh)
-                opt_dev = jax.device_put(opt_reb, opt_sh)
-                reset_err()  # rows permuted across shards
-                # rows moved to different shards: the demand history no
-                # longer describes any edge — drop it and host-probe once
-                ex_demand = None
-                reprobe_exchange(g_dev)
-        if ckpt is not None and ckpt_every and (i + 1) % ckpt_every == 0 \
-                and (i + 1) < steps:
-            save(i + 1)
+        with step_span(i):
+            with span("fit.put"):
+                vi = (i * vb + np.arange(vb)) % V
+                batch = {
+                    "gt_tiles": jax.device_put(jnp.asarray(gt_tiles[vi]),
+                                               b_sh["gt_tiles"]),
+                    "mask_tiles": jax.device_put(jnp.asarray(mask_tiles[vi]),
+                                                 b_sh["mask_tiles"]),
+                    "cam": jax.device_put(select(cams, jnp.asarray(vi)),
+                                          b_sh["cam"]),
+                }
+            step_fn = get_step()
+            with span("fit.dispatch"):
+                if compress == "none":
+                    out = step_fn(g_dev, opt_dev, batch)
+                    g_dev, opt_dev, loss = out[:3]
+                    ov = out[3]
+                else:
+                    out = step_fn(g_dev, opt_dev, err_dev, batch)
+                    g_dev, opt_dev, err_dev, loss = out[:4]
+                    ov = out[4]
+            with span("fit.sync"):
+                losses.append(float(loss))
+            with span("fit.schedule"):
+                if sched is not None:
+                    # a non-zero (psum'd) counter grows the caps for the
+                    # NEXT steps — a one-step blip, never a persistent
+                    # silent truncation
+                    sched.note_overflow(ov["tiles"], m_dev)
+                if assign["impl"] == "sorted" \
+                        and int(np.asarray(ov["assign"]).sum()) > 0:
+                    # radii drifted past the sorted budget's probe slack
+                    # between densify events: grow it geometrically (same
+                    # honesty contract)
+                    assign["budget"] = grow_tile_budget(
+                        assign["budget"] or DEFAULT_TILE_BUDGET,
+                        grid.n_tiles)
+                if ex is not None:
+                    # matrix budgets grow only the starved edges (per-edge
+                    # psum'd counter); scalar budgets keep the total-count
+                    # contract
+                    ex.note_overflow(ov.get("exchange_edges",
+                                            ov["exchange"]), Nl)
+                    if "exchange_demand" in ov:
+                        dm = np.asarray(ov["exchange_demand"])
+                        ex_demand = dm if ex_demand is None \
+                            else np.maximum(ex_demand, dm)
+            if densify_every and i >= densify_from \
+                    and (i + 1) % densify_every == 0:
+                with span("fit.densify"):
+                    ks = jax.random.split(key, 1 + Pn)
+                    key = ks[0]
+                    g_dev, opt_dev = densify(g_dev, opt_dev, ks[1:])
+                    # the vmapped densify jit picks its own output
+                    # shardings; pin the state back onto the step's
+                    # (pod, part) layout before the next donating pjit call
+                    g_dev = jax.device_put(g_dev, g_sh)
+                    opt_dev = jax.device_put(opt_dev, opt_sh)
+                    reset_err()  # row count changed: residuals misaligned
+                    probe_assign(g_dev)  # splat sizes shifted: re-size
+                    if sched is not None:
+                        reprobe(g_dev)  # occupancy shifted: re-pick tiers
+                    if ex is not None and not ex_pinned \
+                            and ex_demand is not None:
+                        # in-step resize, no host probe round-trip:
+                        # densify clones at most cfg.max_new rows per
+                        # partition, so the running per-edge demand +
+                        # max_new upper-bounds the post-densify overlap
+                        ex.ensure(ex_demand + cfg.max_new, Nl)
+                    else:
+                        reprobe_exchange(g_dev)  # overlap shifted too
+            if rebalance_every and (i + 1) % rebalance_every == 0:
+                with span("fit.densify"):
+                    g_reb, opt_reb, moved = rebalance_partitions(
+                        g_dev, opt_dev, mesh, threshold=rebalance_threshold)
+                    if moved:
+                        g_dev = jax.device_put(g_reb, g_sh)
+                        opt_dev = jax.device_put(opt_reb, opt_sh)
+                        reset_err()  # rows permuted across shards
+                        # rows moved to different shards: the demand
+                        # history no longer describes any edge — drop it
+                        # and host-probe once
+                        ex_demand = None
+                        reprobe_exchange(g_dev)
+            if ckpt is not None and ckpt_every \
+                    and (i + 1) % ckpt_every == 0 and (i + 1) < steps:
+                with span("fit.ckpt"):
+                    save(i + 1)
         if log_every and (i + 1) % log_every == 0:
             print(f"  step {i+1:5d}  loss {losses[-1]:.4f}  "
                   f"schedule {sched if sched else 'dense'}")
     if ckpt is not None and steps > start:
-        save(steps)
+        with span("fit.ckpt"):
+            save(steps)
     return g_dev, opt_dev, losses

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core.cameras import Camera
 from repro.core.gaussians import Gaussians, covariance3d, small_matmul
+from repro.core.trace import scope
 
 # anti-aliasing dilation as in 3D-GS reference (0.3 px)
 COV2D_DILATE = 0.3
@@ -34,6 +35,7 @@ class Splats2D(NamedTuple):
     valid: jax.Array      # (...,) bool
 
 
+@scope("project")
 def project(g: Gaussians, cam: Camera, *, near: float = 0.05,
             alpha_min: float = 1.0 / 255.0) -> Splats2D:
     """Project all gaussians for one camera. Fully vectorised over leading dims."""

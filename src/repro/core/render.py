@@ -49,6 +49,7 @@ from repro.core.tiling import (
     tile_origins,
     untile_image,
 )
+from repro.core.trace import scope
 from repro.kernels import rasterize_tiles
 from repro.kernels.ops import rasterize_tiles_batched, rasterize_tiles_tiered
 
@@ -394,17 +395,19 @@ def render_batch_tables(g: Gaussians, cams: Camera, grid: TileGrid,
     table's trailing dim — ``tiling.slice_table`` serves lower ladder
     rungs from one cached Kmax table.
     """
-    feat = jax.vmap(lambda cam: splat_features(project(g, cam)),
-                    in_axes=(CAM_VAXES,))(cams)               # (V, N, F)
-    feat = cast_tables(feat, dtype_policy)   # bf16 storage under the policy
-    idx = lax.stop_gradient(idx)
-    score = lax.stop_gradient(score)
-    tile_feats = jax.vmap(gather_features_at)(feat, idx, score)
-    tiles = rasterize_tiles_batched(
-        tile_feats, tile_origins(grid),
-        tile_h=grid.tile_h, tile_w=grid.tile_w, impl=impl)
-    img = jax.vmap(lambda t: untile_image(t, grid))(tiles)
-    return _composite(img, bg)
+    with scope("gather"):
+        feat = jax.vmap(lambda cam: splat_features(project(g, cam)),
+                        in_axes=(CAM_VAXES,))(cams)           # (V, N, F)
+        feat = cast_tables(feat, dtype_policy)   # bf16 storage (policy)
+        idx = lax.stop_gradient(idx)
+        score = lax.stop_gradient(score)
+        tile_feats = jax.vmap(gather_features_at)(feat, idx, score)
+    with scope("raster"):
+        tiles = rasterize_tiles_batched(
+            tile_feats, tile_origins(grid),
+            tile_h=grid.tile_h, tile_w=grid.tile_w, impl=impl)
+        img = jax.vmap(lambda t: untile_image(t, grid))(tiles)
+        return _composite(img, bg)
 
 
 @functools.lru_cache(maxsize=64)

@@ -37,11 +37,17 @@ Three serving mechanisms ride on the batcher:
       Kmax table down to the shed K via ``tiling.slice_table``).  Shed
       requests and over-cap rejections are counted.
 
-Telemetry follows the PR-6 honesty contract: every budget that can drop
-or degrade work has a counter (``hits/misses/evictions/cache_overflow/
-shed/rejected`` plus the render-side ``tiles``/``assign`` overflow keys),
-and a zero counter is the machine-checked statement that nothing was
-dropped.  Contract suite: tests/test_serving.py; CLI: launch/serve_gs.py.
+Telemetry follows the honesty contract: every budget that can drop or
+degrade work has a counter (``hits/misses/evictions/cache_overflow/
+shed/rejected`` plus the assignment-budget overflow key ``assign``), and a
+zero counter is the machine-checked statement that nothing was dropped.
+Contract suite: tests/test_serving.py; CLI: launch/serve_gs.py.
+
+Tracing (``core.trace``): ``submit`` and ``flush`` run under the host
+spans ``gs.serve.submit`` / ``gs.serve.flush``; each dispatch under
+``gs.serve.dispatch``, which holds ``gs.serve.assign`` (the miss call, up
+to the tables on the host), ``gs.serve.stage`` (stack, slice and upload
+the tables), ``gs.serve.render`` and ``gs.serve.fetch`` (image readback).
 """
 
 from __future__ import annotations
@@ -60,6 +66,7 @@ from repro.core.render import assign_tables_jit, render_tables_jit
 from repro.core.tiling import (DEFAULT_ASSIGN_IMPL, POSE_BINS, TierSchedule,
                                TileGrid, grow_tile_budget, quantize_pose,
                                slice_table)
+from repro.core.trace import span
 
 
 class QueueFullError(RuntimeError):
@@ -296,7 +303,7 @@ class GSRenderServer:
         self._telemetry: Dict[str, int] = {
             "requests": 0, "batches": 0, "hits": 0, "misses": 0,
             "evictions": 0, "cache_overflow": 0, "shed": 0, "rejected": 0,
-            "tiles": 0, "assign": 0,
+            "assign": 0,
         }
 
     # -- checkpoint loading -------------------------------------------------
@@ -356,8 +363,10 @@ class GSRenderServer:
     def telemetry(self) -> Dict[str, int]:
         """Copy of the serving counters.  Honesty contract: ``shed`` /
         ``rejected`` / ``evictions`` / ``cache_overflow`` count every
-        degraded or refused unit of work, ``assign`` / ``tiles`` are the
-        render-side overflow counters (0 == every image exact)."""
+        degraded or refused unit of work; ``assign`` counts assignment
+        candidates dropped past a sorted-path budget (0 == every table
+        exact).  The render from a table drops nothing: it has no tier
+        caps."""
         return dict(self._telemetry)
 
     def clear_cache(self):
@@ -379,38 +388,39 @@ class GSRenderServer:
         queue cap (counted).  Past ``shed_at`` pending requests the
         request is marked shed: still served, at the ladder's
         ``shed_rung`` K (counted, never dropped)."""
-        if np.asarray(cam.view).shape != (4, 4):
-            raise ValueError("submit takes a single-view Camera; use "
-                             "serve() for a batched rig")
-        if (cam.width, cam.height) != (self.grid.width, self.grid.height):
-            raise ValueError(
-                f"camera {cam.width}x{cam.height} does not match the "
-                f"serving grid {self.grid.width}x{self.grid.height}")
-        cfg = self.cfg
-        if len(self._queue) >= cfg.queue_cap:
-            self._telemetry["rejected"] += 1
-            raise QueueFullError(
-                f"request queue at cap {cfg.queue_cap}; rejection counted "
-                "(telemetry['rejected'])")
-        shed_at = cfg.shed_at if cfg.shed_at is not None \
-            else max(1, cfg.queue_cap // 2)
-        shed = len(self._queue) >= shed_at
-        key, (cview, cfx, cfy) = quantize_pose(
-            cam.view, cam.fx, cam.fy, bins=cfg.pose_bins)
-        canon = Camera(jnp.asarray(cview), jnp.float32(cfx), jnp.float32(cfy),
-                       cam.width, cam.height)
-        rung = select_rung(camera_distance(cview, self.center),
-                           self.lod_dists)
-        k = int(self.schedule.k_tiers[cfg.shed_rung]) if shed \
-            else int(self.schedule.kmax)
-        rid = self._next_rid
-        self._next_rid += 1
-        self._telemetry["requests"] += 1
-        if shed:
-            self._telemetry["shed"] += 1
-        self._queue.append(_Request(rid=rid, cam=canon, key=key, rung=rung,
-                                    k=k, shed=shed, hit=False))
-        return rid
+        with span("serve.submit", rid=self._next_rid):
+            if np.asarray(cam.view).shape != (4, 4):
+                raise ValueError("submit takes a single-view Camera; use "
+                                 "serve() for a batched rig")
+            if (cam.width, cam.height) != (self.grid.width, self.grid.height):
+                raise ValueError(
+                    f"camera {cam.width}x{cam.height} does not match the "
+                    f"serving grid {self.grid.width}x{self.grid.height}")
+            cfg = self.cfg
+            if len(self._queue) >= cfg.queue_cap:
+                self._telemetry["rejected"] += 1
+                raise QueueFullError(
+                    f"request queue at cap {cfg.queue_cap}; rejection counted "
+                    "(telemetry['rejected'])")
+            shed_at = cfg.shed_at if cfg.shed_at is not None \
+                else max(1, cfg.queue_cap // 2)
+            shed = len(self._queue) >= shed_at
+            key, (cview, cfx, cfy) = quantize_pose(
+                cam.view, cam.fx, cam.fy, bins=cfg.pose_bins)
+            canon = Camera(jnp.asarray(cview), jnp.float32(cfx), jnp.float32(cfy),
+                           cam.width, cam.height)
+            rung = select_rung(camera_distance(cview, self.center),
+                               self.lod_dists)
+            k = int(self.schedule.k_tiers[cfg.shed_rung]) if shed \
+                else int(self.schedule.kmax)
+            rid = self._next_rid
+            self._next_rid += 1
+            self._telemetry["requests"] += 1
+            if shed:
+                self._telemetry["shed"] += 1
+            self._queue.append(_Request(rid=rid, cam=canon, key=key, rung=rung,
+                                        k=k, shed=shed, hit=False))
+            return rid
 
     # -- cache --------------------------------------------------------------
 
@@ -461,12 +471,13 @@ class GSRenderServer:
             impl, budget = self._assign[rung]
             pad = _pad_pow2(len(misses), cfg.max_batch)
             miss_reqs = [reqs[i] for i in misses]
-            cams = self._stack_cams(miss_reqs, pad)
-            idx, score, ov = assign_tables_jit(
-                self.grid, cfg.K, None, impl, budget)(self.ladder[rung],
-                                                      cams)
-            idx, score = np.asarray(idx), np.asarray(score)
-            n_ov = int(np.asarray(ov)[: len(misses)].sum())
+            with span("serve.assign", n=len(misses), pad=pad):
+                cams = self._stack_cams(miss_reqs, pad)
+                idx, score, ov = assign_tables_jit(
+                    self.grid, cfg.K, None, impl, budget)(self.ladder[rung],
+                                                          cams)
+                idx, score = np.asarray(idx), np.asarray(score)
+                n_ov = int(np.asarray(ov)[: len(misses)].sum())
             if n_ov:
                 # starved sorted-path budget: count it and grow for future
                 # misses (already-cached tables stay as extracted — their
@@ -486,19 +497,25 @@ class GSRenderServer:
         as a single view-batched dispatch from assignment tables."""
         cfg = self.cfg
         rung, k = reqs[0].rung, reqs[0].k
-        tables = self._tables_for(reqs, rung)
         pad = _pad_pow2(len(reqs), cfg.max_batch)
-        take = tables + [tables[-1]] * (pad - len(reqs))
-        idx = np.stack([t[0] for t in take])
-        score = np.stack([t[1] for t in take])
-        idx, score = slice_table(idx, score, k)       # shed rungs: prefix
-        cams = self._stack_cams(reqs, pad)
-        out = render_tables_jit(self.grid, cfg.impl, cfg.bg,
-                                dtype_policy=cfg.dtype_policy)(
-            self.ladder[rung], cams, jnp.asarray(idx), jnp.asarray(score))
-        self._telemetry["batches"] += 1
-        rgb = np.asarray(out.rgb)
-        cov = np.asarray(out.coverage)
+        with span("serve.dispatch", rung=rung, k=k, n=len(reqs), pad=pad,
+                  rid=reqs[0].rid):
+            tables = self._tables_for(reqs, rung)
+            with span("serve.stage"):
+                take = tables + [tables[-1]] * (pad - len(reqs))
+                idx = np.stack([t[0] for t in take])
+                score = np.stack([t[1] for t in take])
+                idx, score = slice_table(idx, score, k)   # shed: prefix
+                cams = self._stack_cams(reqs, pad)
+                idx, score = jnp.asarray(idx), jnp.asarray(score)
+            with span("serve.render"):
+                out = render_tables_jit(self.grid, cfg.impl, cfg.bg,
+                                        dtype_policy=cfg.dtype_policy)(
+                    self.ladder[rung], cams, idx, score)
+            self._telemetry["batches"] += 1
+            with span("serve.fetch"):
+                rgb = np.asarray(out.rgb)
+                cov = np.asarray(out.coverage)
         return [RenderResult(request_id=r.rid, rgb=rgb[i], coverage=cov[i],
                              rung=rung, K=k, cache_hit=r.hit, shed=r.shed)
                 for i, r in enumerate(reqs)]
@@ -511,14 +528,16 @@ class GSRenderServer:
         up to ``max_batch`` views (padded to the next power of two, so
         each config compiles a bounded trace set)."""
         reqs, self._queue = self._queue, []
-        groups: Dict[Tuple[int, int], List[_Request]] = {}
-        for r in reqs:
-            groups.setdefault((r.rung, r.k), []).append(r)
         results: List[RenderResult] = []
-        for key in sorted(groups):
-            rs = groups[key]
-            for s in range(0, len(rs), self.cfg.max_batch):
-                results.extend(self._dispatch(rs[s:s + self.cfg.max_batch]))
+        with span("serve.flush", n=len(reqs)):
+            groups: Dict[Tuple[int, int], List[_Request]] = {}
+            for r in reqs:
+                groups.setdefault((r.rung, r.k), []).append(r)
+            for key in sorted(groups):
+                rs = groups[key]
+                for s in range(0, len(rs), self.cfg.max_batch):
+                    results.extend(
+                        self._dispatch(rs[s:s + self.cfg.max_batch]))
         return sorted(results, key=lambda r: r.request_id)
 
     def serve(self, rig: Camera) -> List[RenderResult]:

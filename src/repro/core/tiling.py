@@ -36,6 +36,7 @@ import numpy as np
 from jax import lax
 
 from repro.core.projection import Splats2D
+from repro.core.trace import scope
 
 NEG = -1e30
 
@@ -290,6 +291,7 @@ def _assign_tiles_coarse(splats: Splats2D, grid: TileGrid, *, K: int,
     return idx, score, overflow
 
 
+@scope("assign")
 def assign_tiles(splats: Splats2D, grid: TileGrid, *, K: int = 64,
                  block: int = 4096, coarse: Optional[int] = None,
                  coarse_budget: Optional[int] = None,
@@ -726,6 +728,7 @@ def _segment_topk_sort3(tile, depth, *, n_tiles: int, K: int):
     return idx[:n_tiles, :K], score[:n_tiles, :K]
 
 
+@scope("assign")
 def sorted_assign_window(mx, my, rad, valid, depth, grid: TileGrid, *,
                          K: int, t0=None, n_local: Optional[int] = None,
                          tile_budget: Optional[int] = None):
@@ -1167,6 +1170,7 @@ def splat_features(splats: Splats2D):
     return jnp.pad(feat, ((0, 0),) * (feat.ndim - 1) + ((0, pad),))
 
 
+@scope("gather")
 def gather_features_at(feat, idx, score):
     """Gather rows of a (N, FEAT_DIM) feature table into per-tile lists.
 
@@ -1185,6 +1189,7 @@ def gather_features_at(feat, idx, score):
     )
 
 
+@scope("gather")
 def gather_tile_features(splats: Splats2D, idx, score):
     """Pack per-tile splat features: (T, K, FEAT_DIM).
 

@@ -28,6 +28,7 @@ from repro.core.render import (occupancy_probe_jit, render_batch,
                                resolve_assignment)
 from repro.core.tiling import (DEFAULT_TILE_BUDGET, TierSchedule, TileGrid,
                                grow_tile_budget)
+from repro.core.trace import scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -335,6 +336,7 @@ def make_train_step(cfg: GSTrainCfg, grid: TileGrid, extent: float, *,
 # ---------------------------------------------------------------------------
 
 
+@scope("densify")
 def densify_and_prune(g: Gaussians, opt: GSOptState, key, cfg: GSTrainCfg,
                       extent: float):
     """One densify event. Static shapes throughout: up to ``cfg.max_new``

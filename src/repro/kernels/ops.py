@@ -18,6 +18,9 @@ Three dispatch shapes share these kernels:
   rasterize_tiles_tiered   variable-K: one launch per occupancy tier (each at
                            its own K_i over its own compacted tile list),
                            scattered back into the full flat tile image
+
+Each entry point runs under the ``gs.raster`` device scope
+(``core.trace``), backward launches included.
 """
 
 from __future__ import annotations
@@ -54,6 +57,12 @@ def _pallas_bwd(tile_h, tile_w, interpret, res, gout):
 _rasterize_pallas.defvjp(_pallas_fwd, _pallas_bwd)
 
 
+def _raster_scope():
+    # core imports this module, so the vocabulary is looked up per call
+    from repro.core.trace import scope
+    return scope("raster")
+
+
 def resolve_impl(impl: str) -> str:
     if impl != "auto":
         return impl
@@ -75,16 +84,17 @@ def rasterize_tiles(feats, origins, *, tile_h: int, tile_w: int,
     the exact pre-policy program.  Output is always f32; the backward pass
     rounds the feature cotangents back to the input dtype at this same
     boundary (the transpose of the promote)."""
-    feats = feats.astype(jnp.float32)
-    origins = origins.astype(jnp.float32)
     impl = resolve_impl(impl)
-    if impl == "ref":
-        return ref_impl.rasterize_tiles_ref(feats, origins,
-                                            tile_h=tile_h, tile_w=tile_w)
-    if impl == "pallas":
-        return _rasterize_pallas(feats, origins, tile_h, tile_w, False)
-    if impl == "interpret":
-        return _rasterize_pallas(feats, origins, tile_h, tile_w, True)
+    with _raster_scope():
+        feats = feats.astype(jnp.float32)
+        origins = origins.astype(jnp.float32)
+        if impl == "ref":
+            return ref_impl.rasterize_tiles_ref(feats, origins,
+                                                tile_h=tile_h, tile_w=tile_w)
+        if impl == "pallas":
+            return _rasterize_pallas(feats, origins, tile_h, tile_w, False)
+        if impl == "interpret":
+            return _rasterize_pallas(feats, origins, tile_h, tile_w, True)
     raise ValueError(impl)
 
 
@@ -98,13 +108,14 @@ def rasterize_tiles_batched(feats, origins, *, tile_h: int, tile_w: int,
     unflattened afterwards.  Semantics are identical to V independent
     ``rasterize_tiles`` calls (tiles are independent programs)."""
     V, T, K, F = feats.shape
-    if origins.ndim == 2:
-        origins = jnp.broadcast_to(origins[None], (V,) + origins.shape)
-    out = rasterize_tiles(
-        feats.reshape(V * T, K, F), origins.reshape(V * T, 2),
-        tile_h=tile_h, tile_w=tile_w, impl=impl,
-    )
-    return out.reshape(V, T, 4, tile_h, tile_w)
+    with _raster_scope():
+        if origins.ndim == 2:
+            origins = jnp.broadcast_to(origins[None], (V,) + origins.shape)
+        out = rasterize_tiles(
+            feats.reshape(V * T, K, F), origins.reshape(V * T, 2),
+            tile_h=tile_h, tile_w=tile_w, impl=impl,
+        )
+        return out.reshape(V, T, 4, tile_h, tile_w)
 
 
 def rasterize_tiles_tiered(tier_feats, tier_origins, tier_ids, n_tiles: int,
@@ -131,11 +142,12 @@ def rasterize_tiles_tiered(tier_feats, tier_origins, tier_ids, n_tiles: int,
     Tier capacities are static, so this traces to a fixed launch schedule —
     cap_i == 0 tiers are skipped at trace time ("non-empty tier" dispatch).
     """
-    out = jnp.zeros((n_tiles, 4, tile_h, tile_w), jnp.float32)
-    for feats, origins, ids in zip(tier_feats, tier_origins, tier_ids):
-        if feats.shape[0] == 0:
-            continue
-        tiles = rasterize_tiles(feats, origins, tile_h=tile_h, tile_w=tile_w,
-                                impl=impl)
-        out = out.at[ids].set(tiles, mode="drop")
-    return out
+    with _raster_scope():
+        out = jnp.zeros((n_tiles, 4, tile_h, tile_w), jnp.float32)
+        for feats, origins, ids in zip(tier_feats, tier_origins, tier_ids):
+            if feats.shape[0] == 0:
+                continue
+            tiles = rasterize_tiles(feats, origins, tile_h=tile_h,
+                                    tile_w=tile_w, impl=impl)
+            out = out.at[ids].set(tiles, mode="drop")
+        return out

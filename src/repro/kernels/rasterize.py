@@ -25,6 +25,9 @@ VMEM budget per program (production tile 8x128, K=64):
 Layouts: feats (T, K, 16) f32, origins (T, 2) f32, out (T, 4, th, tw) f32
 (channels [r, g, b, coverage]).
 
+The two ``pallas_call``s are named ``raster_fwd`` and ``raster_bwd``, so the
+compiled kernels keep one name whatever transform calls them.
+
 K is a trace-time constant, not a baked-in config: each pallas_call
 specializes its (1, K, F) block spec and fori_loop bound to the incoming
 feats shape.  The variable-K tiered dispatch (kernels/ops.
@@ -139,6 +142,7 @@ def rasterize_fwd(feats, origins, *, tile_h: int, tile_w: int,
         out_specs=pl.BlockSpec((1, 4, tile_h, tile_w), lambda t: (t, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((T, 4, tile_h, tile_w), jnp.float32),
         interpret=interpret,
+        name="raster_fwd",
     )(feats.astype(jnp.float32), _origin_blocks(origins))
 
 
@@ -207,6 +211,7 @@ def rasterize_bwd(feats, origins, out, gout, *, tile_h: int, tile_w: int,
                                memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((T, K, F), jnp.float32),
         interpret=interpret,
+        name="raster_bwd",
     )(
         feats.astype(jnp.float32),
         _origin_blocks(origins),

@@ -8,6 +8,9 @@ Persistent compile cache: ``enable_compile_cache`` leaves an exported
 ``JAX_COMPILATION_CACHE_DIR`` to JAX (which reads it itself) and otherwise
 points the cache at ``.jax_cache/`` in the checkout root — a fixed path, so
 a later process of the same checkout finds what an earlier one compiled.
+The cache key includes the programs' metadata: the op names a device trace
+attributes to ``gs.*`` scopes come from it, and a program that differs from
+a cached one only in metadata must not load the cached op names.
 
 Importing this module does not import jax (the drivers set ``XLA_FLAGS``
 before their first jax import).
@@ -30,10 +33,11 @@ CACHE_DIR = CHECKOUT_ROOT / ".jax_cache"
 
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compile cache -> the directory it uses."""
+    import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
-    import jax
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     return str(CACHE_DIR)
 

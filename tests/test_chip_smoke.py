@@ -105,18 +105,25 @@ def test_full_refuses_cpu(module, tmp_path):
 
 def test_compile_cache_placement(monkeypatch):
     """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes
-    to .jax_cache/ at the checkout root."""
+    to .jax_cache/ at the checkout root.  Either way the key includes the
+    programs' metadata (the op names a trace reads)."""
     from repro.launch import device
 
     before = jax.config.jax_compilation_cache_dir
+    meta = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
         jax.config.update("jax_compilation_cache_dir", None)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
         assert device.enable_compile_cache() == "/elsewhere"
         assert jax.config.jax_compilation_cache_dir is None
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         path = device.enable_compile_cache()
         assert path == str(ROOT / ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == path
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          meta)
