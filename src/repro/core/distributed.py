@@ -77,11 +77,11 @@ from repro.core.projection import project
 from repro.core.render import resolve_assignment
 from repro.core.tiling import (DEFAULT_ASSIGN_IMPL, DEFAULT_TILE_BUDGET,
                                FEAT_DIM, TierSchedule, TileGrid,
+                               _merge_block_topk,
                                bin_tiles_by_occupancy, grow_tile_budget,
                                resolve_assign_impl, sorted_assign_window,
                                splat_features, tile_bounds, tile_image,
                                tile_occupancy, tile_tiers,
-                               topk_by_score_then_index,
                                window_overlap_mask)
 from repro.core.trace import scope, span, step_span
 from repro.core.train import (GSTrainCfg, GSOptState, _check_resume_policy,
@@ -274,22 +274,19 @@ def _assign_tiles_local(mean2d, radius, depth, valid, lo, hi, *, K: int,
     def body(carry, xs):
         top_s, top_i = carry                       # (Pl, Tl, K)
         m, r, d, v, b0 = xs
-        cx = jnp.clip(m[:, None, :, 0], lo[None, :, :1], hi[None, :, :1])
-        cy = jnp.clip(m[:, None, :, 1], lo[None, :, 1:], hi[None, :, 1:])
-        dx = m[:, None, :, 0] - cx
-        dy = m[:, None, :, 1] - cy
+        # plain slices: a None beside an integer index traces as a gather
+        mx, my = m[..., 0][:, None, :], m[..., 1][:, None, :]
+        cx = jnp.clip(mx, lo[:, :1], hi[:, :1])      # (Pl, Tl, block)
+        cy = jnp.clip(my, lo[:, 1:], hi[:, 1:])
+        dx = mx - cx
+        dy = my - cy
         hit = (dx * dx + dy * dy) <= (r * r)[:, None, :]
         score = jnp.where(hit & v[:, None, :], -d[:, None, :], NEG)
-        idx = b0 + jnp.arange(block, dtype=jnp.int32)
-        cat_s = jnp.concatenate([top_s, score], axis=-1)
-        cat_i = jnp.concatenate(
-            [top_i, jnp.broadcast_to(idx, score.shape)], axis=-1)
         # two-key merge (score desc, index asc): the same deterministic
         # tie-break as the global assign_tiles, so strip-local and global
         # assignment agree bit-for-bit even when depths tie at the K
         # boundary (ROADMAP tie-break divergence item)
-        new_s, new_i = topk_by_score_then_index(cat_s, cat_i, K)
-        return (new_s, new_i), None
+        return _merge_block_topk(top_s, top_i, score, b0, K), None
 
     Tl = lo.shape[0]
     init = (jnp.full((Pl, Tl, K), NEG, jnp.float32),

@@ -115,6 +115,26 @@ def topk_by_score_then_index(cat_s, cat_i, K: int):
                                       axis=-1)
 
 
+def _merge_block_topk(top_s, top_i, score, b0, K: int):
+    """One step of the dense sweep: merge a block of CONTIGUOUS splats into
+    the running top-K.
+
+    top_s/top_i (..., K) the carry, score (..., block) the scores of splats
+    b0 .. b0 + block - 1 -> (new_s, new_i) (..., K), bit-identical to
+    ``topk_by_score_then_index`` on the concatenation [carry | block] (the
+    same lax.top_k selection, empty slots included) without building or
+    gathering from the (K + block)-wide index row: a block winner at merged
+    position p >= K is splat b0 + p - K, and a carried winner is read out
+    of the K carried slots by compare-and-select.  On TPU that gather runs
+    element by element and costs about twice the top_k itself.
+    """
+    new_s, sel = lax.top_k(jnp.concatenate([top_s, score], axis=-1), K)
+    slots = jnp.arange(K, dtype=jnp.int32)
+    carried = jnp.where(sel[..., :, None] == slots, top_i[..., None, :],
+                        0).sum(axis=-1, dtype=jnp.int32)
+    return new_s, jnp.where(sel < K, carried, b0 + sel - K)
+
+
 # ---------------------------------------------------------------------------
 # Coarse superblock pre-cull
 # ---------------------------------------------------------------------------
@@ -319,7 +339,7 @@ def assign_tiles(splats: Splats2D, grid: TileGrid, *, K: int = 64,
                 each gaussian block with a two-key sort (score desc, splat
                 index asc) — O(T * block) memory; the index tie-break makes
                 the result independent of the merge order (see
-                topk_by_score_then_index).  This is the test oracle and the
+                _merge_block_topk).  This is the test oracle and the
                 escape hatch — always exact, never drops a candidate.
       "sorted"  duplicate-and-sort scatter (``assign_tiles_sorted``): each
                 splat expands into its overlapped-tile candidates under a
@@ -383,17 +403,15 @@ def assign_tiles(splats: Splats2D, grid: TileGrid, *, K: int = 64,
         top_score, top_idx = carry                  # (T, K)
         mb, rb, db, vb, b0 = xs
         # circle/rect overlap: clamp center to rect, compare distance to radius
-        cx = jnp.clip(mb[None, :, 0], lo[:, :1], hi[:, :1])   # (T, block)
-        cy = jnp.clip(mb[None, :, 1], lo[:, 1:], hi[:, 1:])
-        dx = mb[None, :, 0] - cx
-        dy = mb[None, :, 1] - cy
+        # plain slices: a None beside an integer index traces as a gather
+        mx, my = mb[:, 0][None, :], mb[:, 1][None, :]
+        cx = jnp.clip(mx, lo[:, :1], hi[:, :1])             # (T, block)
+        cy = jnp.clip(my, lo[:, 1:], hi[:, 1:])
+        dx = mx - cx
+        dy = my - cy
         hit = (dx * dx + dy * dy) <= (rb * rb)[None, :]
         score = jnp.where(hit & vb[None, :], -db[None, :], NEG)  # (T, block)
-        idx = b0 + jnp.arange(block, dtype=jnp.int32)[None, :]
-        cat_s = jnp.concatenate([top_score, score], axis=1)
-        cat_i = jnp.concatenate([top_idx, jnp.broadcast_to(idx, score.shape)], 1)
-        new_s, new_i = topk_by_score_then_index(cat_s, cat_i, K)
-        return (new_s, new_i), None
+        return _merge_block_topk(top_score, top_idx, score, b0, K), None
 
     T = grid.n_tiles
     init = (jnp.full((T, K), NEG, jnp.float32), jnp.zeros((T, K), jnp.int32))

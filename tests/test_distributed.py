@@ -65,6 +65,48 @@ def test_trainer_refuses_explicit_mesh_axes():
     auto = make_mesh((1, 1), ("part", "view"))
     assert all(t == jax.sharding.AxisType.Auto for t in auto.axis_types)
 
+
+@pytest.mark.parametrize("block", [
+    max(1024, 4096 // 4),     # the step's block at view batch 4
+    333,                      # does not divide N
+])
+def test_assign_tiles_local_matches_per_partition_dense(block):
+    """The strip-local dense sweep over (Pl, N) partitions equals the
+    single-device dense ``assign_tiles`` of each partition, bit for bit,
+    empty slots included."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.distributed import _assign_tiles_local
+    from repro.core.projection import Splats2D
+    from repro.core.tiling import TileGrid, assign_tiles, tile_bounds
+
+    grid, n, K = TileGrid(64, 48, 8, 16), 2500, 16
+    r = np.random.default_rng(block)
+    mean = r.uniform([-12, -12], [76, 60], (2, n, 2)).astype(np.float32)
+    radius = r.uniform(0.5, 9.0, (2, n)).astype(np.float32)
+    depth = r.integers(1, 40, (2, n)).astype(np.float32)   # many ties
+    valid = r.uniform(size=(2, n)) > 0.1
+    lo, hi = tile_bounds(grid)
+    idx, score, ov = _assign_tiles_local(
+        jnp.asarray(mean), jnp.asarray(radius), jnp.asarray(depth),
+        jnp.asarray(valid), lo, hi, K=K, block=block, impl="dense")
+    assert idx.shape == (2, grid.n_tiles, K) and int(ov) == 0
+    for p in range(2):
+        s = Splats2D(mean2d=jnp.asarray(mean[p]),
+                     cov2d=jnp.ones((n, 3), jnp.float32),
+                     depth=jnp.asarray(depth[p]),
+                     rgb=jnp.zeros((n, 3), jnp.float32),
+                     alpha=jnp.ones(n, jnp.float32),
+                     radius=jnp.asarray(radius[p]),
+                     valid=jnp.asarray(valid[p]))
+        want_i, want_s = assign_tiles(s, grid, K=K, block=block,
+                                      impl="dense")
+        np.testing.assert_array_equal(np.asarray(idx[p]), np.asarray(want_i))
+        np.testing.assert_array_equal(np.asarray(score[p]).view(np.int32),
+                                      np.asarray(want_s).view(np.int32))
+        assert (np.asarray(want_s) > -1e29).any()
+
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

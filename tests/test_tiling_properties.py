@@ -5,6 +5,8 @@ Pins the contract the rasterizer relies on:
     matches a brute-force per-tile circle/rect test + depth sort;
   * live entries come out front-to-back (scores non-increasing = depth
     non-decreasing);
+  * the dense sweep's gather-free merge equals the gathering
+    ``topk_by_score_then_index`` bit for bit, and compiles to no gather;
   * the coarse superblock pre-cull returns identical (idx, score) to the
     dense path on live slots whenever its candidate budget covers the true
     per-superblock occupancy (empty-slot idx values are unspecified);
@@ -21,9 +23,10 @@ import numpy as np
 import pytest
 
 from repro.core.projection import Splats2D
-from repro.core.tiling import (NEG, SORTED_MIN_TILES, TileGrid, assign_tiles,
+from repro.core.tiling import (NEG, SORTED_MIN_TILES, TileGrid,
+                               _merge_block_topk, assign_tiles,
                                assign_tiles_sorted, resolve_assign_impl,
-                               tile_bounds)
+                               tile_bounds, topk_by_score_then_index)
 
 
 def random_splats(seed, n, w, h, *, rmax=9.0, invalid_frac=0.1):
@@ -173,6 +176,76 @@ def test_topk_tiebreak_is_merge_order_invariant():
     sc, ix = np.asarray(score_ref), np.asarray(idx_ref)
     same = (np.diff(sc, axis=1) == 0) & (sc[:, :-1] > NEG / 2)
     assert (np.diff(ix, axis=1)[same] > 0).all()
+
+
+@pytest.mark.parametrize("lead,n,block,K,levels,hit", [
+    ((16,), 300, 64, 8, 5, 0.6),        # 1-D rows; ties straddle slot K
+    ((4, 2, 8), 200, 64, 8, 3, 0.6),    # (V, Pl, T) rows, as vmapped
+    ((8,), 100, 32, 8, 4, 0.0),         # every carry and block empty
+    ((8,), 150, 64, 8, 2, 0.9),         # last block padded (150 = 2x64+22)
+    ((8,), 5, 8, 8, 2, 0.8),            # N < K: block == K, padded
+])
+def test_merge_block_topk_matches_gather_merge(lead, n, block, K, levels,
+                                               hit):
+    """The dense sweep's merge decodes its winners' splat indices instead of
+    gathering them from the merged (K + block)-wide row: at every block of
+    a sweep it must equal ``topk_by_score_then_index`` on the same
+    concatenation, bit for bit, empty slots included."""
+    r = np.random.default_rng(len(lead) * 1000 + n)
+    nb = -(-n // block)
+    sc = np.where(r.uniform(size=lead + (n,)) < hit,
+                  -r.integers(1, levels + 1, lead + (n,)), NEG)
+    sc = np.pad(sc, [(0, 0)] * len(lead) + [(0, nb * block - n)],
+                constant_values=NEG).astype(np.float32)
+    merge = jax.jit(_merge_block_topk, static_argnums=4)
+    gather = jax.jit(topk_by_score_then_index, static_argnums=2)
+    top_s = jnp.full(lead + (K,), NEG, jnp.float32)
+    top_i = jnp.zeros(lead + (K,), jnp.int32)
+    for b in range(nb):
+        b0 = b * block
+        score = jnp.asarray(sc[..., b0:b0 + block])
+        cat_i = jnp.broadcast_to(b0 + jnp.arange(block, dtype=jnp.int32),
+                                 score.shape)
+        want_s, want_i = gather(jnp.concatenate([top_s, score], -1),
+                                jnp.concatenate([top_i, cat_i], -1), K)
+        top_s, top_i = merge(top_s, top_i, score, jnp.int32(b0), K)
+        np.testing.assert_array_equal(np.asarray(top_i), np.asarray(want_i))
+        np.testing.assert_array_equal(np.asarray(top_s).view(np.int32),
+                                      np.asarray(want_s).view(np.int32))
+    top_s = np.asarray(top_s)
+    if hit == 0.0:
+        assert (top_s == NEG).all() and not np.asarray(top_i).any()
+    elif n * hit > 2 * K:
+        # ties really straddle the boundary: some row's live slot-K-1 score
+        # is also held by one of that row's losers
+        kth = top_s[..., -1:]
+        straddle = (sc == kth).sum(-1) > (top_s == kth).sum(-1)
+        assert (straddle & (kth[..., 0] > NEG / 2)).any()
+
+
+@pytest.mark.parametrize("path", ["assign_tiles", "assign_tiles_local"])
+def test_dense_sweep_compiles_without_gather(path):
+    """The dense sweep has no gather, neither as a traced primitive (whose
+    op name the chip trace shows under ``gs.assign``) nor in the compiled
+    program: block winners' indices are arithmetic and carried ones a
+    compare-and-select (the coarse path, whose candidates are data, keeps
+    its gather)."""
+    from repro.core.distributed import _assign_tiles_local
+
+    grid = TileGrid(64, 48, 8, 16)
+    splats = random_splats(5, 700, 64, 48)
+    if path == "assign_tiles":
+        fn = jax.jit(lambda s: assign_tiles(s, grid, K=16, block=256,
+                                            impl="dense"))
+        args = (splats,)
+    else:
+        lo, hi = tile_bounds(grid)
+        fn = jax.jit(lambda m, r, d, v: _assign_tiles_local(
+            m, r, d, v, lo, hi, K=16, block=256, impl="dense"))
+        args = tuple(jnp.stack([x, x]) for x in (
+            splats.mean2d, splats.radius, splats.depth, splats.valid))
+    assert "gather" not in str(jax.make_jaxpr(fn)(*args))
+    assert "gather(" not in fn.lower(*args).compile().as_text()
 
 
 # ---------------------------------------------------------------------------
